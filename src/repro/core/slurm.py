@@ -159,6 +159,13 @@ class SlurmCluster:
 
 
 class LocalSlurmCluster(SlurmCluster):
+    """Runs each job as a subprocess, up to ``max_workers`` at once.
+
+    A TPU chip belongs to one process at a time, and a process that has
+    touched JAX holds it. A campaign whose jobs use the chip therefore needs
+    a parent that never imports JAX and ``max_workers=1`` (one chip job at
+    a time); otherwise a job fails or hangs waiting for the chip."""
+
     def __init__(
         self,
         max_workers: int = 8,

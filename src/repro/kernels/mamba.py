@@ -28,6 +28,8 @@ def _mamba_kernel(
     y_ref,  # [1, L, bd]
     hout_ref,  # [1, bd, St]
     h_scr,  # VMEM [bd, St] fp32
+    u_scr, dt_scr, y_scr,  # VMEM [L, bd] fp32
+    b_scr, c_scr,  # VMEM [L, St] fp32
     *,
     chunk: int,
     n_chunks: int,
@@ -38,29 +40,28 @@ def _mamba_kernel(
     def _init():
         h_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    u = u_ref[0].astype(jnp.float32)  # [L, bd]
-    dt = dt_ref[0].astype(jnp.float32)  # [L, bd]
     A = a_ref[...].astype(jnp.float32)  # [bd, St]
-    B_ = b_ref[0].astype(jnp.float32)  # [L, St]
-    C_ = c_ref[0].astype(jnp.float32)  # [L, St]
 
-    def step(t, carry):
-        h, ys = carry
-        dt_t = jax.lax.dynamic_slice_in_dim(dt, t, 1, 0)[0]  # [bd]
-        u_t = jax.lax.dynamic_slice_in_dim(u, t, 1, 0)[0]
-        b_t = jax.lax.dynamic_slice_in_dim(B_, t, 1, 0)[0]  # [St]
-        c_t = jax.lax.dynamic_slice_in_dim(C_, t, 1, 0)[0]
-        a = jnp.exp(dt_t[:, None] * A)  # [bd, St]
-        h = a * h + (dt_t * u_t)[:, None] * b_t[None, :]
-        y_t = jnp.sum(h * c_t[None, :], axis=1)  # [bd]
-        ys = jax.lax.dynamic_update_slice_in_dim(ys, y_t[None, :], t, 0)
-        return h, ys
+    # Mosaic cannot slice values at a traced offset, nor address one bf16 row
+    # at an arbitrary sublane: stage the chunk in fp32 VMEM and move one
+    # timestep row at a time through the scratch refs
+    for ref, scr in ((u_ref, u_scr), (dt_ref, dt_scr), (b_ref, b_scr), (c_ref, c_scr)):
+        scr[...] = ref[0].astype(jnp.float32)
 
-    h0 = h_scr[...]
-    ys0 = jnp.zeros((chunk, u.shape[1]), jnp.float32)
-    h, ys = jax.lax.fori_loop(0, chunk, step, (h0, ys0))
+    def step(t, h):
+        row = pl.ds(t, 1)
+        dt_t = dt_scr[row, :].T  # [bd, 1]
+        u_t = u_scr[row, :].T
+        b_t = b_scr[row, :]  # [1, St]
+        c_t = c_scr[row, :]
+        a = jnp.exp(dt_t * A)  # [bd, St]
+        h = a * h + (dt_t * u_t) * b_t
+        y_scr[row, :] = jnp.sum(h * c_t, axis=1, keepdims=True).T  # [1, bd]
+        return h
+
+    h = jax.lax.fori_loop(0, chunk, step, h_scr[...])
     h_scr[...] = h
-    y_ref[0, :, :] = ys.astype(y_ref.dtype)
+    y_ref[0, :, :] = y_scr[...].astype(y_ref.dtype)
 
     @pl.when(ic == n_chunks - 1)
     def _done():
@@ -105,7 +106,9 @@ def mamba_scan_bsd(
             jax.ShapeDtypeStruct((b, s, di), u.dtype),
             jax.ShapeDtypeStruct((b, di, st), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bd, st), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bd, st), jnp.float32)]
+        + [pltpu.VMEM((chunk, bd), jnp.float32)] * 3
+        + [pltpu.VMEM((chunk, st), jnp.float32)] * 2,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),

@@ -1,18 +1,27 @@
 """Jitted public wrappers around the Pallas kernels.
 
 Layout adaptation ([B,S,H,Dh] model convention <-> [B,H,S,Dh] kernel
-convention), backend dispatch (``interpret=True`` automatically off-TPU so
-the kernels execute correctly on CPU), and custom_vjp wiring: forward runs
-the kernel, backward rematerializes through the pure-jnp reference — exact
-same math, so gradients are correct while the hot forward path uses the
+convention), backend dispatch (``interpret=True`` automatically on the CPU
+backend so the kernels execute there; any backend other than TPU or CPU is
+an error, never a silent fallback), and custom_vjp wiring: forward runs the
+kernel, backward rematerializes through the pure-jnp reference — exact same
+math, so gradients are correct while the hot forward path uses the
 hand-tiled kernel.
+
+Mosaic kernels cannot be partitioned by the compiler. Given sharding
+``rules``, each wrapper runs its kernel under ``shard_map``: batch over the
+data-parallel axes and heads (attention, RWKV6) or channels (Mamba) over
+the tensor-parallel axis, each where the size divides. Every shard is then
+an independent instance of the same recurrence, so the math is unchanged.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from . import ref
 from .flash_attention import flash_attention_bhsd
@@ -23,13 +32,51 @@ from .rwkv6 import rwkv6_bhsd
 def _interpret(flag: bool | None) -> bool:
     if flag is not None:
         return flag
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels have no path on the {backend!r} backend"
+    )
+
+
+def _axis(rules, name, size: int):
+    """``name`` if it is a mesh axis (or tuple of axes) whose size divides
+    ``size``; otherwise None (that dimension stays whole on every shard)."""
+    if name is None:
+        return None
+    names = name if isinstance(name, tuple) else (name,)
+    n = math.prod(rules.mesh.shape[a] for a in names)
+    return name if size % n == 0 else None
+
+
+def _shard(fn, rules, in_specs, out_specs):
+    return jax.shard_map(fn, mesh=rules.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ------------------------------------------------------------ flash attention
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q, k, v, causal=True, window=None, interpret=None):
+def flash_attention(q, k, v, causal=True, window=None, interpret=None, rules=None):
     """q [B,Sq,H,Dh]; k/v [B,Sk,KV,Dh] -> [B,Sq,H,Dh]."""
+    fn = functools.partial(_flash_attention, causal=causal, window=window,
+                           interpret=interpret)
+    if rules is None:
+        return fn(q, k, v)
+    dp = _axis(rules, rules.batch[0], q.shape[0])
+    tp = _axis(rules, rules.tp, q.shape[2])
+    if tp is not None and _axis(rules, tp, k.shape[2]) is None:
+        # kv heads do not split like q heads: give every q head its kv head
+        # so that each shard's GQA grouping stays local
+        rep = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    spec = P(dp, None, tp, None)
+    return _shard(fn, rules, (spec, spec, spec), spec)(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_attention(q, k, v, causal, window, interpret):
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -50,7 +97,7 @@ def _pick_block(s: int, target: int = 256) -> int:
 
 
 def _fa_fwd(q, k, v, causal, window, interpret):
-    return flash_attention(q, k, v, causal, window, interpret), (q, k, v)
+    return _flash_attention(q, k, v, causal, window, interpret), (q, k, v)
 
 
 def _fa_bwd(causal, window, interpret, res, g):
@@ -60,14 +107,25 @@ def _fa_bwd(causal, window, interpret, res, g):
     return vjp(g)
 
 
-flash_attention.defvjp(_fa_fwd, _fa_bwd)
+_flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
 # ------------------------------------------------------------------- rwkv6
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def rwkv6(r, k, v, logw, u, state0, interpret=None):
+def rwkv6(r, k, v, logw, u, state0, interpret=None, rules=None):
     """All inputs [B,S,H,Dh] (u: [H,Dh]; state0: [B,H,Dh,Dh] fp32).
     Returns (out [B,S,H,Dh], state [B,H,Dh,Dh])."""
+    fn = functools.partial(_rwkv6, interpret=interpret)
+    if rules is None:
+        return fn(r, k, v, logw, u, state0)
+    dp = _axis(rules, rules.batch[0], r.shape[0])
+    tp = _axis(rules, rules.tp, r.shape[2])
+    seq, st = P(dp, None, tp, None), P(dp, tp, None, None)
+    return _shard(fn, rules, (seq, seq, seq, seq, P(tp, None), st),
+                  (seq, st))(r, k, v, logw, u, state0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _rwkv6(r, k, v, logw, u, state0, interpret):
     args = [jnp.swapaxes(t, 1, 2) for t in (r, k, v, logw)]
     out, state = rwkv6_bhsd(*args, u, state0.astype(jnp.float32),
                             interpret=_interpret(interpret))
@@ -75,7 +133,7 @@ def rwkv6(r, k, v, logw, u, state0, interpret=None):
 
 
 def _rwkv_fwd(r, k, v, logw, u, state0, interpret):
-    return rwkv6(r, k, v, logw, u, state0, interpret), (r, k, v, logw, u, state0)
+    return _rwkv6(r, k, v, logw, u, state0, interpret), (r, k, v, logw, u, state0)
 
 
 def _rwkv_bwd(interpret, res, g):
@@ -86,20 +144,32 @@ def _rwkv_bwd(interpret, res, g):
     return vjp(g)
 
 
-rwkv6.defvjp(_rwkv_fwd, _rwkv_bwd)
+_rwkv6.defvjp(_rwkv_fwd, _rwkv_bwd)
 
 
 # ------------------------------------------------------------------- mamba
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def mamba_scan(u, dt, A, B_, C_, h0, interpret=None):
+def mamba_scan(u, dt, A, B_, C_, h0, interpret=None, rules=None):
     """u/dt [B,S,Di]; A [Di,St]; B_/C_ [B,S,St]; h0 [B,Di,St] fp32.
     Returns (y [B,S,Di], h [B,Di,St])."""
+    fn = functools.partial(_mamba_scan, interpret=interpret)
+    if rules is None:
+        return fn(u, dt, A, B_, C_, h0)
+    dp = _axis(rules, rules.batch[0], u.shape[0])
+    tp = _axis(rules, rules.tp, u.shape[2])
+    seq, st = P(dp, None, tp), P(dp, tp, None)
+    bc = P(dp, None, None)
+    return _shard(fn, rules, (seq, seq, P(tp, None), bc, bc, st),
+                  (seq, st))(u, dt, A, B_, C_, h0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _mamba_scan(u, dt, A, B_, C_, h0, interpret):
     return mamba_scan_bsd(u, dt, A, B_, C_, h0.astype(jnp.float32),
                           interpret=_interpret(interpret))
 
 
 def _mamba_fwd(u, dt, A, B_, C_, h0, interpret):
-    return mamba_scan(u, dt, A, B_, C_, h0, interpret), (u, dt, A, B_, C_, h0)
+    return _mamba_scan(u, dt, A, B_, C_, h0, interpret), (u, dt, A, B_, C_, h0)
 
 
 def _mamba_bwd(interpret, res, g):
@@ -108,4 +178,4 @@ def _mamba_bwd(interpret, res, g):
     return vjp(g)
 
 
-mamba_scan.defvjp(_mamba_fwd, _mamba_bwd)
+_mamba_scan.defvjp(_mamba_fwd, _mamba_bwd)

@@ -23,7 +23,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _rwkv6_kernel(
     r_ref, k_ref, v_ref, lw_ref,  # [1, 1, L, Dh]
-    u_ref,  # [1, Dh]
+    u_ref,  # [1, 1, Dh]
     s0_ref,  # [1, 1, Dh, Dh]
     o_ref,  # [1, 1, L, Dh]
     sout_ref,  # [1, 1, Dh, Dh]
@@ -42,19 +42,25 @@ def _rwkv6_kernel(
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     lw = lw_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)  # [Dh]
+    u = u_ref[0].astype(jnp.float32)  # [1, Dh]
 
-    la = jnp.cumsum(lw, axis=0)  # [L, Dh] inclusive log-decay
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive cumsum over the chunk as a lower-triangular matmul (Mosaic
+    # has no cumsum); HIGHEST keeps the log-decays at fp32 accuracy
+    tril = jnp.where(cols <= rows, 1.0, 0.0)
+    la = jax.lax.dot_general(
+        tril, lw, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )  # [L, Dh] inclusive log-decay
     q_ = r * jnp.exp(la - lw)  # r_t * A_{t-1}
     k_ = k * jnp.exp(-la)  # k_s / A_s
     scores = jax.lax.dot_general(
         q_, k_, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # [L, L]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     scores = jnp.where(cols < rows, scores, 0.0)  # strictly lower triangular
-    diag = jnp.sum(r * u[None, :] * k, axis=1)  # bonus term, [L]
-    scores = scores + jnp.diag(diag)
+    bonus = jnp.sum(r * u * k, axis=1, keepdims=True)  # [L, 1]
+    scores = scores + jnp.where(cols == rows, bonus, 0.0)  # diag(bonus)
     intra = jax.lax.dot_general(
         scores, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # [L, Dh]
@@ -99,7 +105,7 @@ def rwkv6_bhsd(
             pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, ic: (b_, h_, ic, 0)),
             pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, ic: (b_, h_, ic, 0)),
             pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, ic: (b_, h_, ic, 0)),
-            pl.BlockSpec((1, d), lambda b_, h_, ic: (h_, 0)),
+            pl.BlockSpec((1, 1, d), lambda b_, h_, ic: (h_, 0, 0)),
             pl.BlockSpec((1, 1, d, d), lambda b_, h_, ic: (b_, h_, 0, 0)),
         ],
         out_specs=[
@@ -115,5 +121,5 @@ def rwkv6_bhsd(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(r, k, v, logw, u, state0)
+    )(r, k, v, logw, u.reshape(h, 1, d), state0)
     return out, state

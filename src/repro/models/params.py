@@ -68,12 +68,22 @@ def _init_one(path: str, d: ParamDef, seed: int, dtype) -> jax.Array:
     return (jax.random.normal(key, d.shape, jnp.float32) * scale).astype(dtype)
 
 
-def init_params(defs: dict, seed: int, dtype=jnp.bfloat16) -> dict:
-    """Materialize parameters (smoke tests / real training)."""
+def init_params(defs: dict, seed: int, dtype=jnp.bfloat16, mesh=None) -> dict:
+    """Materialize parameters (smoke tests / real training). With a
+    ``mesh``, each leaf is generated directly under its ``NamedSharding``,
+    so a model that fits only when sharded never lands whole on one device;
+    the values are the same as without one."""
+
+    def one(path: str, d: ParamDef) -> jax.Array:
+        # jitted with or without a mesh: one compiled program per leaf, so
+        # both give the same bits (eager and fused arithmetic can differ)
+        sharding = NamedSharding(mesh, d.spec) if mesh is not None else None
+        return jax.jit(lambda: _init_one(path, d, seed, dtype),
+                       out_shardings=sharding)()
 
     def walk(node, prefix):
         return {
-            name: _init_one(f"{prefix}/{name}", child, seed, dtype)
+            name: one(f"{prefix}/{name}", child)
             if _is_def(child)
             else walk(child, f"{prefix}/{name}")
             for name, child in node.items()

@@ -58,9 +58,9 @@ def _c(x, rules: ShardingRules | None, spec) -> jax.Array:
 
 def _use_pallas(cfg: ModelConfig) -> bool:
     """'auto' -> only on real TPU backends; 'on' forces the kernels (they run
-    in interpret mode off-TPU); 'off' keeps the pure-jnp blockwise paths
-    (the dry-run default — TPU Pallas calls don't lower on the CPU AOT
-    backend)."""
+    in interpret mode on the CPU backend); 'off' keeps the pure-jnp
+    blockwise paths (the dry-run default — TPU Pallas calls don't lower on
+    the CPU AOT backend)."""
     if cfg.use_pallas == "on":
         return True
     if cfg.use_pallas == "off":
@@ -325,14 +325,16 @@ def _self_attention(cfg, rules, p, x, ctx: Ctx, cache):
         kc, vc = cache_insert(cache["k"], cache["v"], k, v, ctx.pos)
         out = decode_attention(q, kc, vc, ctx.pos, ring=ring)
         new_cache = {"k": kc, "v": vc}
-    elif _use_pallas(cfg) and q.shape[1] % 64 == 0:
-        from ..kernels.ops import flash_attention
-        out = flash_attention(q, k, v, ctx.causal, cfg.sliding_window)
     else:
-        out = attention(
-            q, k, v, causal=ctx.causal, window=cfg.sliding_window,
-            q_chunk=cfg.attn_q_chunk, unroll_chunks=cfg.attn_unroll_chunks,
-        )
+        if _use_pallas(cfg) and q.shape[1] % 64 == 0:
+            from ..kernels.ops import flash_attention
+            out = flash_attention(q, k, v, ctx.causal, cfg.sliding_window,
+                                  rules=rules)
+        else:
+            out = attention(
+                q, k, v, causal=ctx.causal, window=cfg.sliding_window,
+                q_chunk=cfg.attn_q_chunk, unroll_chunks=cfg.attn_unroll_chunks,
+            )
         if ctx.mode == "prefill":
             new_cache = _prefill_kv_cache(cfg, rules, ctx, k, v)
     B, S = x.shape[:2]
@@ -417,7 +419,8 @@ def _rwkv_block(cfg, rules, p, x, ctx: Ctx, cache):
         s0 = state0 if state0 is not None else jnp.zeros(
             (B, H, Dh, Dh), jnp.float32
         )
-        out, wkv = rwkv6_kernel(r, k, v, logw.astype(r.dtype), pr["tm_u"], s0)
+        out, wkv = rwkv6_kernel(r, k, v, logw.astype(r.dtype), pr["tm_u"], s0,
+                                rules=rules)
     else:
         out, wkv = ssm.rwkv6_chunked(r, k, v, logw, pr["tm_u"], state0)
     out = rmsnorm(out.reshape(B, S, H * Dh), pr["tm_ln"], cfg.norm_eps) * g
@@ -466,7 +469,8 @@ def _mamba_mixer(cfg, rules, p, x, ctx: Ctx, cache):
     elif _use_pallas(cfg) and S % 64 == 0 and Di % 64 == 0:
         from ..kernels.ops import mamba_scan
         h00 = h0 if h0 is not None else jnp.zeros((B, Di, St), jnp.float32)
-        y, hs = mamba_scan(u, dt, A, B_.astype(u.dtype), C_.astype(u.dtype), h00)
+        y, hs = mamba_scan(u, dt, A, B_.astype(u.dtype), C_.astype(u.dtype), h00,
+                           rules=rules)
     else:
         y, hs = ssm.mamba_scan_chunked(u, dt, A, B_, C_, h0)
     y = y + pm["d_skip"][None, None, :] * u

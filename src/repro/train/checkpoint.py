@@ -33,6 +33,7 @@ from ``wait()`` (or the next ``save_async``), never swallowed.
 """
 from __future__ import annotations
 
+import hashlib
 import io
 import json
 import os
@@ -44,6 +45,7 @@ import ml_dtypes
 import numpy as np
 
 from ..core.annex import make_pointer
+from ..core.hashing import make_annex_key
 from ..core.records import RunRecord
 from ..core.repo import Repository
 from ..core.spec import RunSpec
@@ -90,6 +92,33 @@ def _npy_header(raw: np.ndarray) -> bytes:
     if not header.startswith(magic):
         header = magic + header
     return header
+
+
+def _raw(arr: np.ndarray) -> np.ndarray:
+    """The C-ordered array whose npy serialization stores ``arr``."""
+    raw = arr
+    if arr.dtype == ml_dtypes.bfloat16:  # numpy can't serialize bf16
+        raw = arr.view(np.uint16)
+    if not raw.flags.c_contiguous:
+        # ascontiguousarray would also promote 0-d to 1-d; only copy when
+        # the buffer really isn't C-order
+        raw = np.ascontiguousarray(raw)
+    return raw
+
+
+def leaf_keys(state) -> dict[str, str]:
+    """{leaf path: the annex key an unchunked save stores it under} — a
+    content digest that equals the manifest's ``key`` exactly when the leaf
+    is bit-identical to the saved one."""
+    out = {}
+    for path, v in _flatten(state).items():
+        raw = _raw(np.asarray(jax.device_get(v)))
+        header = _npy_header(raw)
+        h = hashlib.sha256()
+        for block in _npy_stream(header, raw):
+            h.update(block)
+        out[path] = make_annex_key(h.hexdigest(), len(header) + raw.nbytes)
+    return out
 
 
 def _npy_stream(header: bytes, raw: np.ndarray, block: int = _BLOCK):
@@ -176,13 +205,7 @@ class CheckpointManager:
         for path, arr in host.items():
             fname = path.replace("/", ".") + ".npy"
             dtype_name = str(arr.dtype)
-            raw = arr
-            if arr.dtype == ml_dtypes.bfloat16:  # numpy can't serialize bf16
-                raw = arr.view(np.uint16)
-            if not raw.flags.c_contiguous:
-                # ascontiguousarray would also promote 0-d to 1-d; only
-                # copy when the buffer really isn't C-order
-                raw = np.ascontiguousarray(raw)
+            raw = _raw(arr)
             header = _npy_header(raw)
             chunked = self.repo._should_chunk(len(header) + raw.nbytes)
             key = self.repo.annex.put_stream(
@@ -293,17 +316,9 @@ class CheckpointManager:
         )
         return self.repo.annex.read(entry["key"])
 
-    def restore(self, commitish: str | None = None, shardings=None,
-                fetch_workers: int | None = None):
-        """Returns (state_tree, manifest). ``shardings``: optional pytree (or
-        flat {path: sharding}) to device_put leaves under — this is the
-        elastic-resume path (different mesh than at save time).
-
-        Leaves are resolved to annex keys from the manifest, a batched
-        ``has_many`` finds what is already local, missing keys delta-fetch
-        (only chunks not shared with already-restored checkpoints move), and
-        reassembly runs on ``fetch_workers`` threads so concurrent read
-        streams split the aggregate bandwidth (§9)."""
+    def manifest(self, commitish: str | None = None):
+        """(commit oid, manifest) of a checkpoint commit — the newest one by
+        default; (None, None) when there is none. Reads no leaf."""
         if commitish is None:
             latest = self.latest()
             if latest is None:
@@ -315,8 +330,29 @@ class CheckpointManager:
         )
         step = rec.extras["checkpoint_step"]
         reldir = f"{self.subdir}/step_{step:08d}"
-        manifest = json.loads(self._tree_bytes(oid, f"{reldir}/manifest.json"))
-        leaves = manifest["leaves"]
+        return oid, json.loads(self._tree_bytes(oid, f"{reldir}/manifest.json"))
+
+    def restore(self, commitish: str | None = None, shardings=None,
+                fetch_workers: int | None = None, subtree: str | None = None):
+        """Returns (state_tree, manifest). ``shardings``: optional pytree (or
+        flat {path: sharding}) to device_put leaves under — this is the
+        elastic-resume path (different mesh than at save time).
+        ``subtree`` (e.g. ``"params"``) reads only the leaves under it, so
+        serving never fetches optimizer moments.
+
+        Leaves are resolved to annex keys from the manifest, a batched
+        ``has_many`` finds what is already local, missing keys delta-fetch
+        (only chunks not shared with already-restored checkpoints move), and
+        reassembly runs on ``fetch_workers`` threads so concurrent read
+        streams split the aggregate bandwidth (§9)."""
+        oid, manifest = self.manifest(commitish)
+        if manifest is None:
+            return None, None
+        reldir = f"{self.subdir}/step_{manifest['step']:08d}"
+        leaves = {
+            path: meta for path, meta in manifest["leaves"].items()
+            if subtree is None or path.startswith(subtree + "/")
+        }
         # resolve each leaf to an annex key; legacy checkpoints (no "key" in
         # the manifest) fall back to the committed tree entry, where small
         # leaves may be inline blobs

@@ -204,3 +204,16 @@ if HAVE_HYP:
         assert np.isfinite(np.asarray(got)).all()
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """Interpret mode is the CPU's path; any other non-TPU backend is an
+    error, never a silent fallback."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="no path"):
+            ops._interpret(None)
+    else:
+        assert ops._interpret(None) is interpret
+    assert ops._interpret(True) is True  # an explicit choice is kept

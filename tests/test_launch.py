@@ -104,3 +104,93 @@ def test_cell_runnable_rules():
                                      configs.SHAPES["long_500k"])[0] is True
     assert configs.cell_runnable(configs.get("internlm2_20b"),
                                  configs.SHAPES["train_4k"])[0] is True
+
+
+def test_compile_cache_keeps_the_environment_directory(monkeypatch):
+    import jax
+
+    from repro.launch.compile_cache import ENV, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(ENV, "/elsewhere/cache")
+    assert enable_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before  # JAX reads ENV itself
+
+
+def test_compile_cache_defaults_to_one_fixed_checkout_path(monkeypatch):
+    import os
+
+    import jax
+
+    from repro.launch.compile_cache import DEFAULT_DIR, ENV, enable_compile_cache
+
+    checkout = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    monkeypatch.delenv(ENV, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert enable_compile_cache() == DEFAULT_DIR == enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == DEFAULT_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert DEFAULT_DIR == os.path.join(checkout, ".jax_cache")
+
+
+def test_train_then_serve_from_the_checkpoint_commit(tmp_path, monkeypatch):
+    """The launchers in-process: train writes a checkpoint commit, serve
+    restores that commit's parameters and generates from them."""
+    from repro.launch import serve, train
+    from repro.launch.compile_cache import ENV
+
+    monkeypatch.setenv(ENV, str(tmp_path / "cache"))  # leave JAX's cache as is
+    repo = str(tmp_path / "run")
+    res = train.main(["--arch", "qwen3_0_6b", "--steps", "2", "--ckpt-every", "2",
+                      "--repo", repo, "--seq-len", "16", "--batch", "2"])
+    assert res.end_step == 2 and len(res.losses) == 2
+    out = serve.main(["--arch", "qwen3_0_6b", "--repo", repo, "--batch", "2",
+                      "--prompt-len", "8", "--gen", "3"])
+    assert out.commit == res.checkpoint_commit
+    assert out.tokens.shape == (2, 3)
+    assert out.first_decode_logits.shape == (2, configs.get_smoke("qwen3_0_6b").padded_vocab)
+
+
+SMOKE_REHEARSAL = r"""
+import os, shutil, sys, tempfile
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path.insert(0, sys.argv[1])
+import chip_smoke
+from repro.models import transformer as T
+
+# take the kernels (in interpret mode) wherever the chip would take them
+T._use_pallas = lambda cfg: cfg.use_pallas != "off"
+work = tempfile.mkdtemp()
+try:
+    if sys.argv[2] == "one_chip":
+        chip_smoke.one_chip(
+            work, full=False, candidates=((2, 128),), expect_kernels=False,
+            kernel_shapes={"flash": dict(B=2, H=4, KV=2, S=128, Dh=64),
+                           "rwkv6": dict(B=1, H=2, S=64, Dh=32),
+                           "mamba": dict(B=1, S=128, Di=256, St=16)})
+    else:
+        chip_smoke.four_chips(work, full=False, batch=4, seq_len=64,
+                              expect_kernels=False)
+finally:
+    shutil.rmtree(work, ignore_errors=True)
+print("REHEARSAL OK")
+"""
+
+
+@pytest.mark.parametrize("phase", ["one_chip", "four_chips"])
+def test_chip_smoke_phases_at_smoke_size(phase, tmp_path):
+    """chip_smoke.py's phases end to end on the CPU at smoke widths, the
+    kernels in interpret mode and four virtual devices for the mesh."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    out = subprocess.run([sys.executable, "-c", SMOKE_REHEARSAL, root, phase],
+                         capture_output=True, text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert out.stdout.rstrip().endswith("REHEARSAL OK")

@@ -16,7 +16,7 @@ from repro.models import transformer as T
 from repro.models.params import init_params
 from repro.optim.adamw import AdamW, cosine_schedule, global_norm
 from repro.optim.compression import compress_int8, decompress_int8, ef_compress_tree
-from repro.train.checkpoint import CheckpointManager
+from repro.train.checkpoint import CheckpointManager, leaf_keys
 from repro.train.loop import train_segment
 from repro.train.steps import greedy_decode, make_train_step
 
@@ -244,3 +244,30 @@ def test_greedy_decode_runs():
 def test_global_norm_matches_numpy():
     tree = {"a": jnp.asarray([3.0]), "b": {"c": jnp.asarray([4.0])}}
     assert abs(float(global_norm(tree)) - 5.0) < 1e-6
+
+
+def test_leaf_keys_match_the_saved_manifest(repo):
+    """leaf_keys digests a state the way an unchunked save keys it, so a
+    restored state is bit-identical exactly when its keys are the manifest's."""
+    params = init_params(T.param_defs(CFG), seed=0)
+    opt_state = AdamW().init(params)
+    ckpt = CheckpointManager(repo)
+    ckpt.save(1, params, opt_state)
+    _, manifest = ckpt.manifest()
+    keys = leaf_keys({"params": params, "opt_state": opt_state})
+    assert keys == {p: m["key"] for p, m in manifest["leaves"].items()}
+    restored, _ = ckpt.restore()
+    assert leaf_keys(restored) == keys
+    params["final_norm"] = params["final_norm"].at[0].add(1.0)
+    assert leaf_keys({"params": params})["params/final_norm"] != keys["params/final_norm"]
+
+
+def test_restore_subtree_reads_only_those_leaves(repo):
+    params = init_params(T.param_defs(CFG), seed=0)
+    opt_state = AdamW().init(params)
+    ckpt = CheckpointManager(repo)
+    oid = ckpt.save(1, params, opt_state)
+    state, manifest = ckpt.restore(oid, subtree="params")
+    assert set(state) == {"params"} and manifest["step"] == 1
+    assert leaves_equal(state["params"], params)
+    assert ckpt.manifest(oid) == (oid, manifest)
