@@ -110,6 +110,7 @@ def flash_attention_bhsd(
     )
     return pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),

@@ -89,6 +89,7 @@ def mamba_scan_bsd(
     kernel = functools.partial(_mamba_kernel, chunk=chunk, n_chunks=nc)
     y, h = pl.pallas_call(
         kernel,
+        name="mamba_scan",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, bd), lambda b_, id_, ic: (b_, ic, id_)),

@@ -99,6 +99,7 @@ def rwkv6_bhsd(
     kernel = functools.partial(_rwkv6_kernel, chunk=chunk, n_chunks=nc)
     out, state = pl.pallas_call(
         kernel,
+        name="rwkv6",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, ic: (b_, h_, ic, 0)),

@@ -21,7 +21,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import configs
+from .. import configs, obs
 from ..core.repo import Repository
 from ..models import transformer as T
 from ..models.params import init_params
@@ -74,27 +74,21 @@ def main(argv: list[str] | None = None) -> ServeResult:
         .lower(params, batch).compile()
     print(f"prefill compile: {time.perf_counter() - t0:.3f} s")
 
-    t0 = time.perf_counter()
-    caches, logits = jax.block_until_ready(prefill(params, batch))
-    print(f"prefill: {(time.perf_counter() - t0) * 1e3:.3f} ms")
-    tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)[:, None]
+    with obs.span("repro.serve.prefill", prompt_len=args.prompt_len):
+        caches, logits = prefill(params, batch)
+    with obs.span("repro.serve.sample", token=0):
+        tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)[:, None]
     step = jax.jit(make_decode_step(cfg, None), donate_argnums=(1,))
-    out, lat, first_logits = [tok], [], None
+    out, first_logits = [tok], None
     for i in range(args.gen - 1):
-        t0 = time.perf_counter()
-        logits, caches = step(params, caches, tok,
-                              jnp.asarray(args.prompt_len + i, jnp.int32))
-        jax.block_until_ready(logits)
-        lat.append(time.perf_counter() - t0)
+        pos = args.prompt_len + i
+        with obs.span("repro.serve.decode_step", pos=pos):
+            logits, caches = step(params, caches, tok, jnp.asarray(pos, jnp.int32))
         if first_logits is None:
             first_logits = np.asarray(logits, np.float32)
-        tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)[:, None]
+        with obs.span("repro.serve.sample", token=i + 1):
+            tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)[:, None]
         out.append(tok)
-    if len(lat) > 1:  # the first step includes its compile
-        ms = np.array(lat[1:]) * 1e3
-        print(f"decode compile+first step: {lat[0] * 1e3:.3f} ms; "
-              f"then p50={np.percentile(ms, 50):.3f} ms "
-              f"p95={np.percentile(ms, 95):.3f} ms")
     return ServeResult(prompt, np.asarray(jnp.concatenate(out, axis=1)),
                        first_logits, prefill, commit)
 

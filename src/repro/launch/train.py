@@ -17,7 +17,7 @@ from __future__ import annotations
 import argparse
 import os
 
-from .. import configs
+from .. import configs, obs
 from ..core.repo import Repository
 from ..data.tokens import SyntheticTokens
 from ..optim.adamw import AdamW, cosine_schedule
@@ -58,12 +58,14 @@ def main(argv: list[str] | None = None) -> SegmentResult:
     ds = SyntheticTokens(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
                          global_batch=args.batch, seed=0)
     opt = make_optimizer(cfg, args.lr, args.steps)
+    compiles = obs.counters().get("compile", 0)
     res = train_segment(repo, cfg, ds, n_steps=args.steps,
                         ckpt_every=args.ckpt_every, optimizer=opt,
                         async_ckpt=args.async_ckpt)
     print(f"steps {res.start_step} -> {res.end_step}  loss {res.final_loss:.4f}")
     print(f"checkpoint commit: {res.checkpoint_commit}")
-    print(f"checkpoint restore {res.restore_s:.3f} s, saves {res.save_s:.3f} s")
+    print(f"checkpoint restore {res.restore_s:.3f} s, saves {res.save_s:.3f} s, "
+          f"compiles {obs.counters().get('compile', 0) - compiles:g}")
     return res
 
 

@@ -510,7 +510,8 @@ def apply_block(cfg, rules, kind: LayerKind, p, x, ctx: Ctx, cache):
         x, new_cache = _rwkv_block(cfg, rules, p, x, ctx, cache)
         return _c(x, rules, rules.residual if rules else None), new_cache, jnp.zeros((), jnp.float32)
     if kind.mixer == "attn":
-        mix, new_cache = _self_attention(cfg, rules, p, x, ctx, cache)
+        with jax.named_scope("attention"):
+            mix, new_cache = _self_attention(cfg, rules, p, x, ctx, cache)
     else:
         mix, new_cache = _mamba_mixer(cfg, rules, p, x, ctx, cache)
     x = x + mix
@@ -518,7 +519,8 @@ def apply_block(cfg, rules, kind: LayerKind, p, x, ctx: Ctx, cache):
         xmix, xcache = _cross_attention(cfg, rules, p, x, ctx, cache)
         x = x + xmix
         new_cache = {**new_cache, **xcache}
-    ffn_out, aux = _ffn_or_moe(cfg, rules, kind, p, x, ctx)
+    with jax.named_scope("ffn"):
+        ffn_out, aux = _ffn_or_moe(cfg, rules, kind, p, x, ctx)
     x = x + ffn_out
     x = _c(x, rules, rules.residual if rules else None)
     return x, new_cache, aux
@@ -561,6 +563,7 @@ def _run_blocks(cfg, rules, blocks, x, ctx: Ctx, caches=None, pattern=None):
     return x, new_caches, aux
 
 
+@jax.named_scope("embed")
 def _embed_inputs(cfg, params, batch) -> jax.Array:
     tokens = batch["tokens"]
     x = jnp.take(params["embed"], tokens, axis=0)
@@ -581,6 +584,7 @@ def _encode(cfg, rules, params, batch, ctx_mode: str):
     return rmsnorm(enc_x, params["enc_final_norm"], cfg.norm_eps)
 
 
+@jax.named_scope("head")
 def _logits(cfg, params, x) -> jax.Array:
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

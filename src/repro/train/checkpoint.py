@@ -44,6 +44,7 @@ import jax
 import ml_dtypes
 import numpy as np
 
+from .. import obs
 from ..core.annex import make_pointer
 from ..core.hashing import make_annex_key
 from ..core.records import RunRecord
@@ -137,6 +138,22 @@ def _npy_stream(header: bytes, raw: np.ndarray, block: int = _BLOCK):
         yield mv[i : i + block]
 
 
+def _watch_ready(step: int, arrays: list):
+    """A thread holding the span ``repro.ckpt.restore.ready`` open from now
+    until every array in ``arrays`` is ready on its device. The caller never
+    waits for it."""
+    def watch():
+        with obs.span("repro.ckpt.restore.ready", step=step):
+            try:
+                jax.block_until_ready(arrays)
+            except jax.errors.JaxRuntimeError:
+                pass  # a leaf already donated to a step, which waits for it
+
+    thread = threading.Thread(target=watch, name="ckpt-restore-ready", daemon=True)
+    thread.start()
+    return thread
+
+
 class CheckpointManager:
     def __init__(
         self,
@@ -148,6 +165,7 @@ class CheckpointManager:
         self.subdir = subdir
         self.fetch_workers = fetch_workers
         self._thread: threading.Thread | None = None
+        self._ready: threading.Thread | None = None  # the last restore's watcher
         self._async_exc: BaseException | None = None
         # checkpoints() cache, per branch: ref tip the entries were computed
         # at, every commit oid already walked, and the (ts, oid, step) rows
@@ -163,9 +181,7 @@ class CheckpointManager:
         extra: dict | None = None,
         message: str = "",
     ) -> str:
-        state = {"params": params, "opt_state": opt_state}
-        flat = _flatten(state)
-        host = {p: np.asarray(jax.device_get(v)) for p, v in flat.items()}
+        host = self._snapshot(step, params, opt_state)
         return self._write(step, host, data_step, extra, message)
 
     def save_async(self, step, params, opt_state, data_step=0, extra=None,
@@ -174,8 +190,7 @@ class CheckpointManager:
         A failure of the previous async save is re-raised here (and from
         :meth:`wait`) — it is never silently dropped."""
         self.wait()
-        flat = _flatten({"params": params, "opt_state": opt_state})
-        host = {p: np.asarray(jax.device_get(v)) for p, v in flat.items()}
+        host = self._snapshot(step, params, opt_state)
 
         def work():
             try:
@@ -186,65 +201,79 @@ class CheckpointManager:
         self._thread = threading.Thread(target=work)
         self._thread.start()
 
+    def _snapshot(self, step, params, opt_state) -> dict:
+        """The state's leaves copied to the host, flat by path."""
+        flat = _flatten({"params": params, "opt_state": opt_state})
+        with obs.span("repro.ckpt.snapshot", step=step,
+                      bytes=sum(v.nbytes for v in flat.values())):
+            return {p: np.asarray(jax.device_get(v)) for p, v in flat.items()}
+
     def wait(self) -> None:
         """Block until the in-flight async save completes; re-raise its
         failure, if any."""
         if self._thread is not None:
-            self._thread.join()
+            with obs.span("repro.ckpt.wait"):
+                self._thread.join()
             self._thread = None
+        if self._ready is not None:  # long done: the loop has used the state
+            self._ready.join()
+            self._ready = None
         exc, self._async_exc = self._async_exc, None
         if exc is not None:
             raise exc
 
     def _write(self, step, host: dict, data_step, extra, message) -> str:
-        reldir = f"{self.subdir}/step_{step:08d}"
-        absdir = os.path.join(self.repo.root, reldir)
-        fs = self.repo.fs
-        manifest = {"step": step, "data_step": data_step, "leaves": {},
-                    "extra": extra or {}}
-        for path, arr in host.items():
-            fname = path.replace("/", ".") + ".npy"
-            dtype_name = str(arr.dtype)
-            raw = _raw(arr)
-            header = _npy_header(raw)
-            chunked = self.repo._should_chunk(len(header) + raw.nbytes)
-            key = self.repo.annex.put_stream(
-                _npy_stream(header, raw), chunked=chunked
-            )
+        with obs.span("repro.ckpt.write", step=step,
+                      bytes=sum(a.nbytes for a in host.values())):
+            reldir = f"{self.subdir}/step_{step:08d}"
+            absdir = os.path.join(self.repo.root, reldir)
+            fs = self.repo.fs
+            manifest = {"step": step, "data_step": data_step, "leaves": {},
+                        "extra": extra or {}}
+            for path, arr in host.items():
+                fname = path.replace("/", ".") + ".npy"
+                dtype_name = str(arr.dtype)
+                raw = _raw(arr)
+                header = _npy_header(raw)
+                chunked = self.repo._should_chunk(len(header) + raw.nbytes)
+                key = self.repo.annex.put_stream(
+                    _npy_stream(header, raw), chunked=chunked
+                )
+                fs.write_bytes(
+                    os.path.join(absdir, fname), make_pointer(key, chunked=chunked)
+                )
+                manifest["leaves"][path] = {
+                    "file": fname,
+                    "shape": list(arr.shape),
+                    "dtype": dtype_name,
+                    "key": key,
+                    "chunked": chunked,
+                }
             fs.write_bytes(
-                os.path.join(absdir, fname), make_pointer(key, chunked=chunked)
+                os.path.join(absdir, "manifest.json"),
+                json.dumps(manifest, indent=1, sort_keys=True).encode(),
             )
-            manifest["leaves"][path] = {
-                "file": fname,
-                "shape": list(arr.shape),
-                "dtype": dtype_name,
-                "key": key,
-                "chunked": chunked,
-            }
-        fs.write_bytes(
-            os.path.join(absdir, "manifest.json"),
-            json.dumps(manifest, indent=1, sort_keys=True).encode(),
-        )
-        # §10 crash matrix: a crash here leaves published leaves/chunks but
-        # no commit — recovery sees zero divergence, gc sweeps the orphans
-        fs.crash_point("ckpt:leaves-written")
-        spec = RunSpec(cmd=f"checkpoint --step {step}", outputs=(reldir,))
-        record = RunRecord(
-            cmd=spec.cmd,
-            dsid=self.repo.dsid,
-            outputs=[reldir],
-            extras={"checkpoint_step": step, "data_step": data_step,
-                    **(extra or {})},
-        )
-        msg = message or f"{MARKER} step {step}"
-        if MARKER not in msg:
-            msg = f"{MARKER} {msg}"
-        oid = self.repo.save(
-            paths=[reldir], message=record.to_message(msg),
-            spec=spec.to_json(),
-        )
-        fs.crash_point("ckpt:after-commit")
-        return oid
+            # §10 crash matrix: a crash here leaves published leaves/chunks but
+            # no commit — recovery sees zero divergence, gc sweeps the orphans
+            fs.crash_point("ckpt:leaves-written")
+            spec = RunSpec(cmd=f"checkpoint --step {step}", outputs=(reldir,))
+            record = RunRecord(
+                cmd=spec.cmd,
+                dsid=self.repo.dsid,
+                outputs=[reldir],
+                extras={"checkpoint_step": step, "data_step": data_step,
+                        **(extra or {})},
+            )
+            msg = message or f"{MARKER} step {step}"
+            if MARKER not in msg:
+                msg = f"{MARKER} {msg}"
+            with obs.span("repro.ckpt.commit", step=step):
+                oid = self.repo.save(
+                    paths=[reldir], message=record.to_message(msg),
+                    spec=spec.to_json(),
+                )
+            fs.crash_point("ckpt:after-commit")
+            return oid
 
     # ---------------------------------------------------------- restore
     def _walk(self, head: str, seen: set, old_head: str | None):
@@ -345,6 +374,16 @@ class CheckpointManager:
         (only chunks not shared with already-restored checkpoints move), and
         reassembly runs on ``fetch_workers`` threads so concurrent read
         streams split the aggregate bandwidth (§9)."""
+        with obs.span("repro.ckpt.restore") as span:
+            state, manifest = self._restore(commitish, shardings, fetch_workers, subtree)
+            if manifest is None:
+                return None, None
+            leaves = jax.tree.leaves(state)
+            span.set_metadata(step=manifest["step"], bytes=sum(a.nbytes for a in leaves))
+        self._ready = _watch_ready(manifest["step"], leaves)
+        return state, manifest
+
+    def _restore(self, commitish, shardings, fetch_workers, subtree):
         oid, manifest = self.manifest(commitish)
         if manifest is None:
             return None, None
@@ -388,11 +427,12 @@ class CheckpointManager:
 
         items = list(jobs.items())
         workers = fetch_workers if fetch_workers is not None else self.fetch_workers
-        if workers > 1 and len(items) > 1:
-            with ThreadPool(min(workers, len(items))) as pool:
-                loaded = pool.map(fetch, items)
-        else:
-            loaded = [fetch(it) for it in items]
+        with obs.span("repro.ckpt.restore.fetch"):
+            if workers > 1 and len(items) > 1:
+                with ThreadPool(min(workers, len(items))) as pool:
+                    loaded = pool.map(fetch, items)
+            else:
+                loaded = [fetch(it) for it in items]
         arrays = dict(loaded)
         flat_shardings = (
             _flatten(shardings) if isinstance(shardings, dict) else None

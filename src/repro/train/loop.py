@@ -15,6 +15,7 @@ import jax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from .. import obs
 from ..configs.base import ModelConfig
 from ..core.repo import Repository
 from ..models import transformer as T
@@ -79,42 +80,49 @@ def train_segment(
     ``rules.mesh`` with the parameter shardings; without, on the default
     device."""
     optimizer = optimizer or AdamW(lr=1e-3, moment_dtype=cfg.opt_moment_dtype)
-    ckpt = CheckpointManager(repo)
-    step_fn, init_opt, shardings, batch_sharding = jit_train_step(
-        cfg, rules, optimizer)
+    with obs.span("repro.train.segment", end_step=n_steps) as segment:
+        ckpt = CheckpointManager(repo)
+        step_fn, init_opt, shardings, batch_sharding = jit_train_step(
+            cfg, rules, optimizer)
+        t0 = time.perf_counter()
+        state, manifest = ckpt.restore(shardings=shardings)
+        restore_s = time.perf_counter() - t0
+        if state is not None:
+            params, opt_state = state["params"], state["opt_state"]
+            start = int(manifest["step"])
+        else:
+            params = init_params(T.param_defs(cfg, rules), seed=seed,
+                                 mesh=rules.mesh if rules is not None else None)
+            opt_state = init_opt(params)
+            start = 0
+        segment.set_metadata(start_step=start)
 
-    t0 = time.perf_counter()
-    state, manifest = ckpt.restore(shardings=shardings)
-    restore_s = time.perf_counter() - t0
-    if state is not None:
-        params, opt_state = state["params"], state["opt_state"]
-        start = int(manifest["step"])
-    else:
-        params = init_params(T.param_defs(cfg, rules), seed=seed,
-                             mesh=rules.mesh if rules is not None else None)
-        opt_state = init_opt(params)
-        start = 0
-
-    losses: list[float] = []
-    commit = None
-    save_s = 0.0
-    for step in range(start, n_steps):
-        tokens = dataset.shard_batch_at(step, 0, 1)
-        batch = {"tokens": jax.device_put(tokens, batch_sharding)}
-        params, opt_state, metrics = step_fn(params, opt_state, batch)
-        losses.append(float(metrics["loss"]))
-        if (step + 1) % ckpt_every == 0 or step + 1 == n_steps:
-            saver = ckpt.save_async if async_ckpt else ckpt.save
-            t0 = time.perf_counter()
-            out = saver(
-                step + 1, params, opt_state, data_step=step + 1,
-                extra={"loss": losses[-1], "config": cfg.name},
-            )
-            save_s += time.perf_counter() - t0
-            commit = out if isinstance(out, str) else commit
-    t0 = time.perf_counter()
-    ckpt.wait()
-    save_s += time.perf_counter() - t0
+        losses: list[float] = []
+        commit = None
+        save_s = 0.0
+        for step in range(start, n_steps):
+            with obs.span("repro.train.feed", step=step):
+                tokens = dataset.shard_batch_at(step, 0, 1)
+                batch = {"tokens": jax.device_put(tokens, batch_sharding)}
+            # the job's first call traces, lowers and compiles (or loads) the
+            # step; every later one only dispatches it
+            name = "repro.train.first_step" if step == start else "repro.train.step"
+            with obs.span(name, step=step):
+                params, opt_state, metrics = step_fn(params, opt_state, batch)
+            with obs.span("repro.train.loss", step=step):
+                losses.append(float(metrics["loss"]))
+            if (step + 1) % ckpt_every == 0 or step + 1 == n_steps:
+                saver = ckpt.save_async if async_ckpt else ckpt.save
+                t0 = time.perf_counter()
+                out = saver(
+                    step + 1, params, opt_state, data_step=step + 1,
+                    extra={"loss": losses[-1], "config": cfg.name},
+                )
+                save_s += time.perf_counter() - t0
+                commit = out if isinstance(out, str) else commit
+        t0 = time.perf_counter()
+        ckpt.wait()
+        save_s += time.perf_counter() - t0
     if commit is None:
         latest = ckpt.latest()
         commit = latest[0] if latest else None
