@@ -80,8 +80,7 @@ def _flash_attention(q, k, v, causal, window, interpret):
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    bq = _pick_block(q.shape[1])
-    bk = _pick_block(k.shape[1])
+    bq, bk = flash_tiles(q.shape[1], k.shape[1], q.shape[3], q.dtype)
     out = flash_attention_bhsd(
         qt, kt, vt, causal=causal, window=window,
         block_q=bq, block_k=bk, interpret=_interpret(interpret),
@@ -89,11 +88,26 @@ def _flash_attention(q, k, v, causal, window, interpret):
     return jnp.swapaxes(out, 1, 2)
 
 
-def _pick_block(s: int, target: int = 256) -> int:
-    b = min(target, s)
-    while s % b:
-        b //= 2
-    return max(b, 1)
+def _tile(s: int, align: int, cap: int) -> int:
+    """The largest multiple of ``align`` that divides ``s`` and is at most
+    ``cap``; ``s`` itself where there is none."""
+    for t in range(min(cap, s) // align * align, 0, -align):
+        if s % t == 0:
+            return t
+    return s
+
+
+def flash_tiles(sq: int, sk: int, d: int, dtype) -> tuple[int, int]:
+    """(bq, bk) of the flash kernel: the largest divisors of the lengths
+    that are multiples of the dtype's sublane tile (8 rows of 32 bits), up
+    to a 1024 x 1024 score tile, with fewer query rows past Dh 128 so that
+    the kernel stays within the default scoped VMEM. On a TPU v5e the
+    largest such tile was the fastest at every served and trained length
+    (PERF.md); a lane width that is not a multiple of 128 (992 at 3968) is
+    padded, and still beats the 128-wide tiles that divide such lengths."""
+    sublane = 32 // jnp.dtype(dtype).itemsize
+    return (_tile(sq, sublane, 1024 * 128 // max(d, 128)),
+            _tile(sk, sublane, 1024))
 
 
 def _fa_fwd(q, k, v, causal, window, interpret):

@@ -22,8 +22,15 @@ from repro.distributed.sharding import make_rules
 from repro.kernels import ops
 from repro.launch.mesh import make_host_mesh
 
-# real widths: qwen3 attention, rwkv6_1_6b heads, jamba_1_5_large_398b scan
-FLASH = dict(B=2, H=16, KV=8, S=2048, Dh=128)
+# real widths: qwen3 attention, rwkv6_1_6b heads, jamba_1_5_large_398b scan;
+# flash also at granite-3-2b's longest served prefill (S = 31 * 128, tiles
+# 992 wide) and at the qwen3 training step's length
+FLASH = {
+    "flash": dict(B=2, H=16, KV=8, S=2048, Dh=128),
+    "flash_granite_prefill": dict(B=16, H=32, KV=8, S=3968, Dh=64),
+    "flash_qwen3_train": dict(B=4, H=16, KV=8, S=4096, Dh=128),
+}
+KERNELS = [*FLASH, "rwkv6", "mamba"]
 RWKV6 = dict(B=2, H=32, S=512, Dh=64)
 MAMBA = dict(B=1, S=512, Di=16384, St=16)
 
@@ -51,8 +58,8 @@ def _kernel_args(name: str, sds):
     """(kernel call, argument stand-ins) for one kernel; ``sds(shape, dtype,
     spec)`` builds a stand-in, ``spec`` naming the sharded dimensions."""
     bf16, f32 = jnp.bfloat16, jnp.float32
-    if name == "flash":
-        s = FLASH
+    if name in FLASH:
+        s = FLASH[name]
         heads = P(None, None, "model", None)
         args = (sds((s["B"], s["S"], s["H"], s["Dh"]), bf16, heads),
                 sds((s["B"], s["S"], s["KV"], s["Dh"]), bf16, heads),
@@ -84,7 +91,7 @@ def _assert_kernel_in(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("name", ["flash", "rwkv6", "mamba"])
+@pytest.mark.parametrize("name", KERNELS)
 def test_kernel_compiles_for_one_chip(topo, name):
     one = SingleDeviceSharding(topo.devices[0])
     make, args = _kernel_args(
@@ -92,7 +99,7 @@ def test_kernel_compiles_for_one_chip(topo, name):
     _assert_kernel_in(jax.jit(make(None)).lower(*args).compile())
 
 
-@pytest.mark.parametrize("name", ["flash", "rwkv6", "mamba"])
+@pytest.mark.parametrize("name", KERNELS)
 def test_kernel_compiles_under_shard_map_on_2x2(topo, name):
     """Mosaic kernels cannot be partitioned by the compiler; with sharding
     rules the wrappers run them per shard under shard_map."""

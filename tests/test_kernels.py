@@ -1,12 +1,17 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles,
 executed with interpret=True (kernel bodies run on CPU)."""
+import functools
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ops, ref
+from repro.kernels.flash_attention import NEG_INF
 from repro.models import ssm as model_ssm
 
 try:
@@ -35,6 +40,10 @@ TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5), jnp.bfloat16: dict(rtol=2e-2, at
         (1, 256, 256, 4, 4, 64, True, 64),  # sliding window
         (1, 128, 128, 2, 2, 96, False, None),  # encoder (non-causal), Dh=96
         (2, 64, 64, 4, 2, 32, True, 16),
+        # S = 31 * 128, the longest served prompt: 992-wide tiles
+        (1, 3968, 3968, 2, 1, 64, True, None),
+        (1, 3968, 3968, 2, 1, 64, True, 1000),
+        (1, 3968, 3968, 2, 1, 64, False, None),
     ],
 )
 def test_flash_attention_vs_ref(b, sq, sk, h, kv, dh, causal, window, dtype):
@@ -69,6 +78,176 @@ def test_flash_attention_block_sweep():
         )
     for o in outs[1:]:
         np.testing.assert_allclose(o, outs[0], rtol=1e-5, atol=1e-5)
+
+
+def _full_grid_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                      scale, causal, window, bq, bk, nk):
+    """A full-grid flash kernel, the oracle of the skipping schedule: every
+    KV block of every query block is computed, each under the mask."""
+    ik = pl.program_id(3)
+    iq = pl.program_id(2)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    q = q_ref[0, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    if causal:
+        rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        cols = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        mask = cols <= rows
+        if window is not None:
+            mask &= cols > rows - window
+        s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_scr[:, 0:1]
+    l_prev = l_scr[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(s > 0.5 * NEG_INF, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.where(m_prev > 0.5 * NEG_INF, jnp.exp(m_prev - m_new), 1.0)
+    l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(ik == nk - 1)
+    def _done():
+        l = l_scr[:, 0:1]
+        l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, 0, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def _full_grid_flash(q, k, v, causal, window, bq, bk):
+    b, h, sq, d = q.shape
+    kv, sk = k.shape[1], k.shape[2]
+    g = h // kv
+    nq, nk = sq // bq, sk // bk
+    kernel = functools.partial(_full_grid_kernel, scale=d**-0.5, causal=causal,
+                               window=window, bq=bq, bk=bk, nk=nk)
+    return pl.pallas_call(
+        kernel,
+        grid=(b, h, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, iq, ik: (b_, h_ // g, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, iq, ik: (b_, h_ // g, ik, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
+                        pltpu.VMEM((bq, 128), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)],
+        interpret=True,
+    )(q, k, v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "s,block,h,kv,causal,window",
+    [
+        (256, 64, 2, 2, True, None),
+        (384, 128, 4, 2, True, None),  # GQA
+        (256, 64, 2, 1, True, 64),  # window of one block
+        (384, 64, 2, 2, True, 100),  # window edge inside blocks
+        (256, 64, 2, 2, False, None),
+    ],
+)
+def test_flash_skip_bit_identical_to_full_grid(s, block, h, kv, causal, window, dtype):
+    """Skipping the blocks outside the mask, and not masking the blocks
+    wholly inside it, leaves every bit of the output as it was."""
+    from repro.kernels.flash_attention import flash_attention_bhsd
+
+    rng = np.random.default_rng(s + block + h + (window or 0))
+    q = rand(rng, (1, h, s, 64), dtype)
+    k = rand(rng, (1, kv, s, 64), dtype)
+    v = rand(rng, (1, kv, s, 64), dtype)
+    got = flash_attention_bhsd(q, k, v, causal=causal, window=window,
+                               block_q=block, block_k=block, interpret=True)
+    want = _full_grid_flash(q, k, v, causal, window, block, block)
+    assert np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize(
+    "s,bq,bk,causal,window,closed",
+    [
+        # square tiles: the n(n+1)/2 blocks on and below the diagonal, the n
+        # on it masked
+        (512, 64, 64, True, None, lambda n: (n * n, n * (n + 1) // 2, n)),
+        # bq = m * bk: the m blocks under each query tile's diagonal masked
+        (512, 128, 32, True, None,
+         lambda nq: (nq * 4 * nq, 4 * nq * (nq + 1) // 2, 4 * nq)),
+        # window of w blocks: min(iq, w) + 1 blocks a row, its two edges masked
+        (512, 64, 64, True, 3 * 64,
+         lambda n: (n * n, sum(min(i, 3) + 1 for i in range(n)), n + max(0, n - 3))),
+        (512, 64, 128, False, None, lambda nq: (nq * nq // 2, nq * nq // 2, 0)),
+    ],
+)
+def test_flash_block_counters(s, bq, bk, causal, window, closed):
+    from repro import obs
+    from repro.kernels.flash_attention import block_counts, flash_attention_bhsd
+
+    b, h = 2, 3
+    want = tuple(b * h * n for n in closed(s // bq))
+    assert tuple(b * h * n for n in block_counts(
+        s, s, bq=bq, bk=bk, causal=causal, window=window)) == want
+    x = jax.ShapeDtypeStruct((b, h, s, 64), jnp.float32)
+    before = obs.counters()
+    jax.eval_shape(lambda q, k, v: flash_attention_bhsd(
+        q, k, v, causal=causal, window=window, block_q=bq, block_k=bk,
+        interpret=True), x, x, x)
+    after = obs.counters()
+    got = tuple(after[f"flash.{n}"] - before.get(f"flash.{n}", 0)
+                for n in ("blocks", "blocks_run", "blocks_masked"))
+    assert got == want
+
+
+@pytest.mark.parametrize(
+    "s,bq,bk,causal,window",
+    [
+        (3968, 496, 128, True, None),  # S = 31 * 128, as the longest prompt
+        (3968, 128, 992, True, 1000),
+        (3968, 992, 496, False, None),
+        (512, 128, 64, True, 100),
+        (512, 64, 256, True, None),
+    ],
+)
+def test_flash_uneven_tiles_vs_ref(s, bq, bk, causal, window):
+    from repro.kernels.flash_attention import flash_attention_bhsd
+
+    rng = np.random.default_rng(s + bq + bk)
+    q = rand(rng, (1, s, 2, 64), jnp.float32)
+    k = rand(rng, (1, s, 1, 64), jnp.float32)
+    v = rand(rng, (1, s, 1, 64), jnp.float32)
+    got = flash_attention_bhsd(*(jnp.swapaxes(t, 1, 2) for t in (q, k, v)),
+                               causal=causal, window=window, block_q=bq,
+                               block_k=bk, interpret=True)
+    want = ref.attention_ref(q, k, v, causal, window)
+    np.testing.assert_allclose(np.asarray(jnp.swapaxes(got, 1, 2)),
+                               np.asarray(want), **TOL[jnp.float32])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("d", [64, 128, 256])
+@pytest.mark.parametrize("s", [64, 512, 1024, 2048, 3968, 4096])
+def test_flash_tiles_rule(s, d, dtype):
+    """The largest tiles that divide S in whole sublane tiles, up to a
+    1024 x 1024 score tile and fewer query rows past Dh 128."""
+    bq, bk = ops.flash_tiles(s, s, d, dtype)
+    sublane = 32 // jnp.dtype(dtype).itemsize
+    cap_q, cap_k = 1024 * 128 // max(d, 128), 1024
+    for t, cap in ((bq, cap_q), (bk, cap_k)):
+        assert s % t == 0 and t % sublane == 0 and t <= cap
+        assert not any(s % u == 0 for u in range(t + sublane, cap + 1, sublane))
+    if d <= 128:
+        assert (bq, bk) == {3968: (992, 992)}.get(s, (min(s, 1024),) * 2)
 
 
 def test_flash_attention_grad_matches_ref():

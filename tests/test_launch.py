@@ -168,6 +168,7 @@ try:
         chip_smoke.one_chip(
             work, full=False, candidates=((2, 128),), expect_kernels=False,
             kernel_shapes={"flash": dict(B=2, H=4, KV=2, S=128, Dh=64),
+                           "flash_prefill": dict(B=2, H=4, KV=2, S=384, Dh=64),
                            "rwkv6": dict(B=1, H=2, S=64, Dh=32),
                            "mamba": dict(B=1, S=128, Di=256, St=16)})
     else:
