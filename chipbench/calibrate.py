@@ -81,6 +81,7 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
 
     enable_compile_cache()
+    harness.one_program_per_call()
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         for line in readings(cell, seed, args.fault, args.control, pk, harness.WORK):

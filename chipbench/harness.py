@@ -4,8 +4,10 @@ Everything that belongs to one configuration, traffic mix, cell or metric is
 a file of its own, found by the name ``BENCHMARK.json`` gives it:
 
 - ``chipbench/configs/<config>.json``: the sizes as run, the program's
-  architecture id (``arch``) and overrides, and the name of its plain
-  reference in ``chipbench/references/``;
+  architecture id (``arch``) and overrides, the name of its plain
+  reference in ``chipbench/references/``, and optionally (``costs``) the
+  class that counts its operations and bytes, as ``<module>.<class>`` of
+  ``chipbench`` (``flops.Dense`` where the key is absent);
 - ``chipbench/traffic/<traffic>.json``: the traffic's parameters; its
   ``kind`` names the general generator and driver in ``chipbench/kinds/``;
 - ``chipbench/limits/<cell>.json``: the limit of each number that decides
@@ -147,12 +149,12 @@ class Run:
 
     def __init__(self, cell: Cell, seed: int, seconds: float, trace: bool,
                  peak: dict, work: str):
-        from chipbench.flops import Dense
-
         self.cell, self.seed, self.seconds, self.trace = cell, seed, seconds, trace
         self.config, self.traffic = cell.config, cell.traffic
         self.peak, self.work = peak, work
-        self.model = Dense.from_config(cell.config)
+        module, cls = cell.config.get("costs", "flops.Dense").rsplit(".", 1)
+        costs = getattr(importlib.import_module(f"chipbench.{module}"), cls)
+        self.model = costs.from_config(cell.config)
         self.data: dict = {}  # the driver's records of the window
         self.red = None  # the reduced trace, with --trace 1
 
@@ -214,6 +216,18 @@ def _profiler(run: Run):
     shutil.rmtree(tdir, ignore_errors=True)
 
 
+def one_program_per_call() -> None:
+    """A Pallas kernel's serialised body keeps the innermost Python frames of
+    the call that traced it, and JAX's persistent compilation cache keys on
+    that body. The same train step traced from the set-up job, from the
+    window, or through a benchmark span is then three programs, and the
+    window's compiles inside the window. With no frames in locations it is
+    one program, compiled in set-up and found again in the window."""
+    import jax
+
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+
+
 def judge(checks: dict) -> bool:
     """``checks``: {name: (value, limit)}; correct when every value is a
     number at or under its limit."""
@@ -248,6 +262,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, device: dict,
     from repro.launch.compile_cache import enable_compile_cache
 
     log(f"compile cache: {enable_compile_cache()}")
+    one_program_per_call()
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
     log(f"work dir {work}: {shutil.disk_usage(work).free} bytes free")

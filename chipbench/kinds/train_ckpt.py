@@ -6,12 +6,22 @@ Traffic parameters (``chipbench/traffic/<name>.json``, kind ``train_ckpt``):
 leaves the first commit (and that the reference follows); ``ckpt_every``:
 each job in the window trains to the second multiple of it after its start
 and commits at both, so the first commit can overlap the steps after it;
-``async_ckpt``; ``lr``.
+``async_ckpt``; ``lr``; optionally ``reference_seqs``, the sequences at a
+time the float32 reference takes each set-up step's gradient in (the whole
+batch where it is absent), so that it fits the chips.
 
 Set-up: the job starts from the benchmark's weights, made on the device in
 one jitted call from the seed (``reference.weights``), in the place of the
 program's ``init_params``, which compiles one program per leaf with the seed
 in it. Its first step's optimizer state is copied to the host for the check.
+
+A cell on more than one chip trains on the program's host mesh
+``(data=1, model=chips)`` under its sharding rules: the weights are made
+under the program's parameter shardings, every job steps and restores its
+state under them, the last commit is read back under them, and the
+reference follows the set-up steps with its float32 state laid out over the
+same mesh (``reference.shardings_over``). On one chip every call is the
+program's single-device path.
 
 Window: back-to-back ``repro.train.loop.train_segment`` calls, each resuming
 from the last commit; it ends with the job in progress once ``--seconds``
@@ -29,7 +39,8 @@ Checks (``correct``):
 - ``update_gap``: the same for the change of the weights over the set-up
   steps (as the set-up commit holds them);
 - ``ckpt_leaf_mismatch``: leaves of the last commit whose restored bytes do
-  not hash to the key the manifest records (exact, limit 0);
+  not hash to the key the manifest records, or (on a mesh) that were not
+  restored under the sharding asked for (exact, limit 0);
 - ``resume_breaks``: jobs that did not start where the previous one ended
   (exact, limit 0).
 
@@ -58,15 +69,16 @@ def _gap_by_leaf(got: dict, want: dict, keep) -> float:
     return worst
 
 
-def train_gaps(reference, model, seed: int, traffic: dict, prog: dict, prec: str = "f32") -> dict:
+def train_gaps(reference, model, seed: int, traffic: dict, prog: dict, prec: str = "f32",
+               mesh=None) -> dict:
     """The three training numbers for program readings ``prog`` = {losses,
     params, m1} (the trees flat by path): the reference, in ``prec``, follows
-    the same steps from the same seeded start."""
+    the same steps from the same seeded start (over ``mesh``, if given)."""
     n = len(prog["losses"])
     batches = [reference.synthetic_batch(seed, s, model.vocab, traffic["batch"],
                                          traffic["seq_len"]) for s in range(n)]
-    ref = reference.train_steps(model, seed, batches,
-                                reference.AdamW(lr=traffic["lr"]), prec=prec)
+    ref = reference.train_steps(model, seed, batches, reference.AdamW(lr=traffic["lr"]),
+                                prec=prec, mesh=mesh, seqs=traffic.get("reference_seqs"))
     g1 = ref["g1_norms"]
     med = float(np.median(list(g1.values())))
     keep = [k for k in ref["m1"] if g1[k] >= 1e-3 * med]
@@ -93,7 +105,7 @@ class Driver:
         from repro.train.loop import train_segment
 
         return train_segment(self.repo, self.cfg, self.data, n_steps=n_steps,
-                             ckpt_every=ckpt_every, optimizer=self.opt,
+                             ckpt_every=ckpt_every, optimizer=self.opt, rules=self.rules,
                              seed=self.run.seed, async_ckpt=bool(self.run.traffic["async_ckpt"]))
 
     def setup(self):
@@ -105,6 +117,16 @@ class Driver:
 
         run, t = self.run, self.run.traffic
         self.cfg = program_config(run.config)
+        self.rules = self.shardings = self.mesh = None
+        if run.cell.chips > 1:
+            from repro.distributed.sharding import make_rules
+            from repro.launch.mesh import make_host_mesh
+            from repro.models import transformer as T
+            from repro.train.loop import state_shardings
+
+            self.mesh = make_host_mesh(run.cell.chips)
+            self.rules = make_rules(self.mesh)
+            self.shardings = state_shardings(T.param_defs(self.cfg, self.rules), self.mesh)
         self.repo = Repository.init(os.path.join(run.work, "repo"))
         self.data = SyntheticTokens(self.cfg.vocab_size, self.seq, self.batch, seed=run.seed)
         self.opt = AdamW(lr=float(t["lr"]), moment_dtype=self.cfg.opt_moment_dtype)
@@ -121,8 +143,9 @@ class Driver:
 
         ref = self.run.cell.reference
         model = ref.Model.from_config(self.run.config)
+        place = self.shardings and self.shardings["params"]
         return patched(loop, "init_params",
-                       lambda orig: lambda *a, **k: ref.weights(model, self.run.seed))
+                       lambda orig: lambda *a, **k: ref.weights(model, self.run.seed, place))
 
     def _first_moment(self):
         """The train step, with AdamW's first moment after its first call
@@ -131,8 +154,7 @@ class Driver:
 
         from repro.train import loop
 
-        from chipbench.references.dense_gqa import flatten
-
+        flatten = self.run.cell.reference.flatten
         self.m1 = None
 
         def make(jit_step):
@@ -226,11 +248,9 @@ class Driver:
 
         from repro.train.checkpoint import CheckpointManager
 
-        from chipbench.references.dense_gqa import flatten
-
-        state, _ = CheckpointManager(self.repo).restore(self.first.checkpoint_commit,
-                                                        subtree="params")
-        params = flatten(jax.device_get(state["params"]))
+        state, _ = CheckpointManager(self.repo).restore(
+            self.first.checkpoint_commit, shardings=self.shardings, subtree="params")
+        params = self.run.cell.reference.flatten(jax.device_get(state["params"]))
         del state
         return {"losses": self.first.losses, "params": params, "m1": self.m1}
 
@@ -240,7 +260,7 @@ class Driver:
         run = self.run
         ref = run.cell.reference
         return train_gaps(ref, ref.Model.from_config(run.config), run.seed, run.traffic,
-                          prog if prog is not None else self.first_job(), prec)
+                          prog if prog is not None else self.first_job(), prec, self.mesh)
 
     def control(self) -> dict:
         """The same numbers for the reference itself computed in float8 in
@@ -251,7 +271,8 @@ class Driver:
         batches = [ref.synthetic_batch(run.seed, s, model.vocab, self.batch, self.seq)
                    for s in range(int(run.traffic["setup_steps"]))]
         low = ref.train_steps(model, run.seed, batches, ref.AdamW(lr=run.traffic["lr"]),
-                              prec="fp8")
+                              prec="fp8", mesh=self.mesh,
+                              seqs=run.traffic.get("reference_seqs"))
         return self.readings(low)
 
     def check(self) -> dict:
@@ -260,11 +281,16 @@ class Driver:
         from chipbench.harness import log
 
         lim = self.run.cell.limits
-        # the last commit reads back byte for byte
+        # the last commit reads back byte for byte, under the shardings asked
         state, manifest = CheckpointManager(self.repo).restore(
-            self.segments[-1].checkpoint_commit)
+            self.segments[-1].checkpoint_commit, shardings=self.shardings)
         keys = leaf_keys(state)
         mismatch = sum(keys[p] != m["key"] for p, m in manifest["leaves"].items())
+        if self.shardings is not None:
+            import jax
+
+            mismatch += sum(a.sharding != want for a, want in
+                            zip(jax.tree.leaves(state), jax.tree.leaves(self.shardings)))
         del state
         breaks, prev = 0, self.first
         for r in self.segments:

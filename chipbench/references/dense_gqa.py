@@ -119,15 +119,15 @@ def _scale(shape, init_scale):
     return init_scale if init_scale is not None else fan_in ** -0.5
 
 
-def weights(m: Model, seed: int) -> dict:
+def weights(m: Model, seed: int, shardings=None) -> dict:
     """The weights a run starts from, training and serving alike: made on
     the device in one jitted call from the seed, in bfloat16, one key per
     leaf split from the key of the seed's sha256; each leaf normal times its
-    scale, norms ones."""
+    scale, norms ones. ``shardings``, a tree of the weights' shape, places
+    each leaf as it is made; the values are the same."""
     specs = leaf_specs(m)
     seed32 = int.from_bytes(hashlib.sha256(str(seed).encode()).digest()[:4], "big")
 
-    @jax.jit
     def make(key):
         keys = jax.random.split(key, len(specs))
         flat = {}
@@ -139,7 +139,27 @@ def weights(m: Model, seed: int) -> dict:
                               * _scale(shape, sc)).astype(jnp.bfloat16)
         return nest(flat)
 
+    make = jax.jit(make) if shardings is None else jax.jit(make, out_shardings=shardings)
     return make(jax.random.PRNGKey(seed32))
+
+
+def shardings_over(m: Model, mesh, axis: str = "model") -> dict:
+    """NamedShardings of the weights over ``axis`` of ``mesh``, tensor
+    parallel in the Megatron way: the q, k, v and feed-forward input
+    projections by output column, the attention and feed-forward output
+    projections by input row, the embedding by hidden column, the norms
+    whole. The layout only places the reference's arithmetic; the compiler
+    inserts every exchange it needs, so the values are those of one
+    device."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    by_leaf = {"wq": P(None, None, axis), "wk": P(None, None, axis),
+               "wv": P(None, None, axis), "w1": P(None, None, axis),
+               "w3": P(None, None, axis), "wo": P(None, axis, None),
+               "w2": P(None, axis, None), "embed": P(None, axis)}
+    return nest({path: NamedSharding(mesh, by_leaf.get(path.rsplit("/", 1)[-1], P()))
+                 for path in leaf_specs(m)})
 
 
 def synthetic_batch(seed: int, step: int, vocab: int, batch: int, seq: int) -> np.ndarray:
@@ -196,19 +216,23 @@ def rope(x, positions, theta: float):
 
 
 def attention(q, k, v, prec: str, q_block: int = 512):
-    """Causal GQA: q [B, S, H, Dh], k/v [B, S, KV, Dh] -> [B, S, H, Dh]."""
+    """Causal GQA: q [B, S, H, Dh], k/v [B, S, KV, Dh] -> [B, S, H, Dh].
+    Each block of queries is rematerialised for the backward pass, so that
+    one block's scores, not the whole layer's, are held at a time."""
     B, S, H, Dh = q.shape
     KV = k.shape[2]
     qg = q.reshape(B, S, KV, H // KV, Dh)
     kpos = jnp.arange(S)
-    outs = []
-    for q0 in range(0, S, q_block):
-        qb = qg[:, q0:q0 + q_block]
+
+    def block(qb, k, v, q0):
         s = einsum("bqkgd,bskd->bkgqs", qb, k, prec) * Dh ** -0.5
         qpos = q0 + jnp.arange(qb.shape[1])
         s = jnp.where(kpos[None, :] <= qpos[:, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
-        outs.append(einsum("bkgqs,bskd->bqkgd", p, v, prec))
+        return einsum("bkgqs,bskd->bqkgd", p, v, prec)
+
+    block = jax.checkpoint(block, static_argnums=(3,))
+    outs = [block(qg[:, q0:q0 + q_block], k, v, q0) for q0 in range(0, S, q_block)]
     return jnp.concatenate(outs, axis=1).reshape(B, S, H, Dh)
 
 
@@ -246,9 +270,12 @@ def head_logits(m: Model, params, h, prec: str = "f32"):
     return mm(h, emb.T, prec)
 
 
-def loss(m: Model, params, tokens, prec: str = "f32", row_block: int = 1024):
+def loss(m: Model, params, tokens, prec: str = "f32", row_block: int = 1024,
+         over: int | None = None):
     """Mean next-token cross-entropy over the real vocabulary (the padding
-    rows are masked out, which is the same as leaving them out)."""
+    rows are masked out, which is the same as leaving them out). ``over``:
+    the summed loss is divided by that many predicted tokens in place of
+    this batch's, where the batch is a block of a larger one."""
     h = final_hidden(m, params, tokens, prec)[:, :-1].reshape(-1, m.hidden)
     gold = tokens[:, 1:].reshape(-1)
     n = h.shape[0]
@@ -266,7 +293,25 @@ def loss(m: Model, params, tokens, prec: str = "f32", row_block: int = 1024):
 
     parts = jax.lax.map(block, (h.reshape(-1, row_block, m.hidden),
                                 gold.reshape(-1, row_block), valid.reshape(-1, row_block)))
-    return jnp.sum(parts) / n
+    return jnp.sum(parts) / (over or n)
+
+
+def loss_and_grads(m: Model, params, tokens, prec: str = "f32", seqs: int | None = None):
+    """The batch's mean loss and its gradients, taken ``seqs`` sequences at
+    a time (all at once by default) and summed, so that the memory the
+    float32 backward pass holds is that of one block of sequences."""
+    B, S = tokens.shape
+    if seqs is None or seqs >= B:
+        return jax.value_and_grad(lambda p: loss(m, p, tokens, prec))(params)
+    n = B * (S - 1)
+
+    def block(acc, tok):
+        value, grads = jax.value_and_grad(lambda p: loss(m, p, tok, prec, over=n))(params)
+        return jax.tree.map(jnp.add, acc, (value, grads)), None
+
+    zero = (jnp.zeros((), jnp.float32), jax.tree.map(jnp.zeros_like, params))
+    out, _ = jax.lax.scan(block, zero, tokens.reshape(B // seqs, seqs, S))
+    return out
 
 
 # ----------------------------------------------------------------- training
@@ -280,13 +325,14 @@ class AdamW:
     clip: float = 1.0  # global gradient norm
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2))
-def _train_step(m: Model, opt: AdamW, prec: str, params, mom, vel, t, tokens):
-    """One step: loss and gradients in float32 from the bfloat16 weights,
-    clip, AdamW in float32, weights stored back in bfloat16."""
+@partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _train_step(m: Model, opt: AdamW, prec: str, seqs, params, mom, vel, t, tokens):
+    """One step: loss and gradients in float32 from the bfloat16 weights
+    (``seqs`` sequences at a time), clip, AdamW in float32, weights stored
+    back in bfloat16."""
     p32 = jax.tree.map(lambda p: p.astype(jnp.float32), params)
     with jax.default_matmul_precision("highest"):
-        value, grads = jax.value_and_grad(lambda p: loss(m, p, tokens, prec))(p32)
+        value, grads = loss_and_grads(m, p32, tokens, prec, seqs)
     gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
     grads = jax.tree.map(lambda g: g * jnp.minimum(1.0, opt.clip / (gnorm + 1e-9)), grads)
     t = t + 1.0
@@ -303,20 +349,28 @@ def _train_step(m: Model, opt: AdamW, prec: str, params, mom, vel, t, tokens):
     return value, grads, pick(0), pick(1), pick(2)
 
 
-def train_steps(m: Model, seed: int, batches, opt: AdamW = AdamW(), prec: str = "f32"):
+def train_steps(m: Model, seed: int, batches, opt: AdamW = AdamW(), prec: str = "f32",
+                mesh=None, seqs: int | None = None):
     """Follow the first ``len(batches)`` steps of a training run from its
     seeded start. Returns {losses, p0, params after the last step, m1 (the
     first moment after the first step), g1_norms (each leaf's norm
     of the first clipped gradient)}, the trees flat by path and on the
-    host."""
-    params = weights(m, seed)
+    host. With ``mesh``, the weights and AdamW's moments are laid out over
+    its ``model`` axis (``shardings_over``), for a model whose float32
+    state does not fit one device; ``seqs`` takes each step's gradient that
+    many sequences at a time (``loss_and_grads``)."""
+    params = weights(m, seed, None if mesh is None else shardings_over(m, mesh))
     p0 = flatten(jax.device_get(params))
-    mom = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    vel = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+
+    def zeros(p):
+        return jnp.zeros(p.shape, jnp.float32, device=None if mesh is None else p.sharding)
+
+    mom = jax.tree.map(zeros, params)
+    vel = jax.tree.map(zeros, params)
     losses, g1 = [], None
     for i, tokens in enumerate(batches):
         value, grads, params, mom, vel = _train_step(
-            m, opt, prec, params, mom, vel, jnp.float32(i), jnp.asarray(tokens))
+            m, opt, prec, seqs, params, mom, vel, jnp.float32(i), jnp.asarray(tokens))
         losses.append(float(value))
         if g1 is None:
             g1 = {k: float(np.linalg.norm(np.asarray(v, np.float64)))

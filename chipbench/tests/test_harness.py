@@ -26,9 +26,11 @@ def test_every_cell_names_existing_files(name):
     assert set(cell.readers) == {m["name"] for m in cell.per_layer} != set()
     assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
     assert len(cell.end_to_end) >= 2
-    assert set(cell.limits) == set(tiny.LIMITS[name])  # the numbers the smoke runs judge
     for m in cell.per_layer:
         assert m["moves"] in {e["name"] for e in cell.end_to_end}
+    # the cell's smoke files, and the numbers its smoke runs judge
+    assert tiny.has_smoke(cell.workload), f"{name}: no smoke files under chipbench/tests/smoke"
+    assert set(cell.limits) == set(tiny.smoke("limits", name))
 
 
 def test_every_configuration_states_what_the_contract_asks():
@@ -136,3 +138,51 @@ def test_a_new_cell_configuration_and_metric_are_files_only(tmp_path):
     assert result["correct"] and result["attempted"] == traffic["batch"]
     assert set(result["metrics"]) == {"ttft_p95_ms", "itl_p95_ms", "setup_s"}
     assert list(result)[-1] == "checks"
+
+
+def test_a_step_traced_from_two_call_sites_is_one_program():
+    """The train step, lowered for the TPU with its Pallas kernels from two
+    call sites (as the set-up job and the window trace it), is two programs
+    to the compilation cache while locations keep Python frames, and one
+    once the harness has taken them out."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro import configs
+    from repro.kernels import ops
+    from repro.models import transformer as T
+    from repro.models.params import abstract_params
+    from repro.optim.adamw import AdamW
+    from repro.train.loop import jit_train_step
+
+    cfg = configs.get("qwen3_0_6b").replace(n_layers=1, use_pallas="on")
+    opt = AdamW(lr=1e-3)
+    params = abstract_params(T.param_defs(cfg), jnp.bfloat16)
+    args = (params, jax.eval_shape(opt.init, params),
+            {"tokens": jax.ShapeDtypeStruct((1, 256), jnp.int32)})
+
+    def kernels(traced):
+        text = traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=False)
+        found = re.findall(r"tpu_custom_call.*", text)
+        assert found
+        return found
+
+    def from_setup():
+        return kernels(jit_train_step(cfg, None, opt)[0].trace(*args))
+
+    def from_window():
+        return kernels(jit_train_step(cfg, None, opt)[0].trace(*args))
+
+    was = jax.config.jax_traceback_in_locations_limit
+    interpret = ops._interpret
+    ops._interpret = lambda flag: False  # the TPU kernels, lowered without a TPU
+    try:
+        jax.config.update("jax_traceback_in_locations_limit", 10)  # JAX's default
+        assert from_setup() != from_window()
+        harness.one_program_per_call()
+        assert from_setup() == from_window()
+    finally:
+        ops._interpret = interpret
+        jax.config.update("jax_traceback_in_locations_limit", was)

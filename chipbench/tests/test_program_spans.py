@@ -58,6 +58,9 @@ def _serve_planes():
 
 NEW_READERS = {"ckpt_resume_s", "ckpt_snapshot_s", "ckpt_write_gbps", "train_first_step_s",
                "compiles_in_window.train"}
+FIRST_READERS = {"ckpt_save_stall_s", "ckpt_restore_s", "train_mfu", "flash_fwd_roofline.train",
+                 "idle_share.train", "prefill_mfu", "flash_fwd_roofline.serve",
+                 "decode_roofline", "idle_share.serve"}
 
 
 def _readers():
@@ -71,7 +74,7 @@ def _readers():
 def _run(red):
     seg = SimpleNamespace(restore_s=3.5, save_s=2.8)
     return SimpleNamespace(red=red, traffic={"cycle": {"64": 1}}, peak=peaks.peak("TPU v5 lite"),
-                           model=_model("qwen3-0.6b"),
+                           model=_model("qwen3-0.6b"), cell=SimpleNamespace(chips=1),
                            data={"batch": 2, "seq": 2048, "gen": 8, "segments": [seg], "saves": 2})
 
 
@@ -85,7 +88,10 @@ def test_program_spans_leave_every_earlier_reading_as_it_was(planes):
     assert trace.breakdown(red) == trace.breakdown(plain)
     readers = _readers()
     earlier = set(readers) - NEW_READERS
-    assert len(earlier) == 9
+    # the first benchmark's nine, and every reader added since as a file
+    assert FIRST_READERS <= earlier
+    assert earlier == {f[:-3] for f in os.listdir(os.path.join(BENCH_DIR, "metrics"))
+                       if f.endswith(".py")} - NEW_READERS
     for name in earlier:
         assert readers[name](_run(red)) == readers[name](_run(plain)), name
     if planes is _serve_planes:  # the serving readers find their programs

@@ -1,7 +1,13 @@
-"""The float32 reference agrees with ``models/transformer.py`` at smoke
-widths on the CPU: training logits, loss and gradients, and prefill then
-decode through the cache; and the weights the benchmark makes from the seed
-have the program's tree, shapes and dtype."""
+"""Each configuration's float32 reference agrees with
+``models/transformer.py`` at smoke widths on the CPU: training logits, loss
+and gradients, and prefill then decode through the cache; and the weights
+the benchmark makes from the seed have the program's tree, shapes and dtype.
+Every configuration of ``BENCHMARK.json`` is a case, run through the
+reference its file names (``reference``) against the program's
+architecture it names (``arch``), at the widths of its smoke file."""
+import importlib
+import os
+
 import numpy as np
 import pytest
 
@@ -13,30 +19,33 @@ from repro.models import transformer as T
 from repro.models.params import init_params
 from repro.train.steps import make_decode_step, make_prefill_step, masked_loss
 
-from chipbench.references import dense_gqa as R
-from chipbench.tests.tiny import SMOKE
+from chipbench.tests import tiny
 
-ARCHS = {"qwen3-0.6b": "qwen3_0_6b", "granite-3-2b": "granite_3_2b"}
+CONFIGS = {c["name"]: c["file"] for c in tiny.load(os.path.join(tiny.ROOT, "BENCHMARK.json"))["configs"]}
 
 
 def _pair(name):
-    cfg = configs.get(ARCHS[name]).replace(**SMOKE[name]["program_overrides"])
-    hf = dict(SMOKE[name], rope_theta=cfg.rope_theta, rms_norm_eps=cfg.norm_eps,
-              qk_norm=cfg.qk_norm)
-    return cfg, R.Model.from_config(hf)
+    """(the program's config, the reference module, its model), at smoke
+    widths."""
+    c = tiny.load(os.path.join(tiny.ROOT, CONFIGS[name]))
+    c.update(tiny.smoke("configs", name))
+    R = importlib.import_module(f"chipbench.references.{c['reference']}")
+    cfg = configs.get(c["arch"]).replace(**c["program_overrides"])
+    hf = dict(c, rope_theta=cfg.rope_theta, rms_norm_eps=cfg.norm_eps, qk_norm=cfg.qk_norm)
+    return cfg, R, R.Model.from_config(hf)
 
 
 def _f32(tree):
     return jax.tree.map(lambda a: a.astype(jnp.float32), tree)
 
 
-@pytest.mark.parametrize("name", sorted(ARCHS))
+@pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_training_init_is_the_programs(name):
     """A training job starts from ``R.weights`` in the place of the
     program's ``init_params``: the same tree, shapes and dtype, the norms
     ones, each other leaf at the program's scale, and another seed another
     start."""
-    cfg, m = _pair(name)
+    cfg, R, m = _pair(name)
     got = R.flatten(jax.device_get(R.weights(m, seed=2**31 + 11)))
     want = R.flatten(jax.device_get(init_params(T.param_defs(cfg), seed=2**31 + 11)))
     other = R.flatten(jax.device_get(R.weights(m, seed=2**31 + 12)))
@@ -51,9 +60,9 @@ def test_training_init_is_the_programs(name):
             assert not np.array_equal(got[k], other[k]), k
 
 
-@pytest.mark.parametrize("name", sorted(ARCHS))
+@pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_logits_loss_and_gradients(name):
-    cfg, m = _pair(name)
+    cfg, R, m = _pair(name)
     cfg = cfg.replace(dtype="float32")
     params = _f32(init_params(T.param_defs(cfg), seed=3))
     tokens = jnp.asarray(R.synthetic_batch(5, 0, cfg.vocab_size, 2, 32))
@@ -77,9 +86,9 @@ def test_logits_loss_and_gradients(name):
                                    rtol=2e-3, atol=1e-6, err_msg=k)
 
 
-@pytest.mark.parametrize("name", sorted(ARCHS))
+@pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_prefill_then_decode(name):
-    cfg, m = _pair(name)
+    cfg, R, m = _pair(name)
     cfg = cfg.replace(dtype="float32")
     params = _f32(init_params(T.param_defs(cfg), seed=4))
     prompt = R.synthetic_batch(6, 0, cfg.vocab_size, 2, 16)
@@ -108,7 +117,7 @@ def test_train_steps_follow_the_program():
     from repro.optim.adamw import AdamW
     from repro.train.loop import jit_train_step
 
-    cfg, m = _pair("qwen3-0.6b")
+    cfg, R, m = _pair("qwen3-0.6b")
     batches = [R.synthetic_batch(9, s, cfg.vocab_size, 2, 64) for s in range(3)]
     step, init_opt, _, _ = jit_train_step(cfg, None, AdamW(lr=1e-3))
     params = R.weights(m, seed=9)
@@ -123,3 +132,20 @@ def test_train_steps_follow_the_program():
     for k, v in ref["m1"].items():
         a, b = np.linalg.norm(np.asarray(m1[k], np.float64)), np.linalg.norm(v)
         assert abs(a - b) <= 0.05 * b + 1e-12, k
+
+
+def test_a_batch_taken_in_blocks_of_sequences_gives_the_same_loss_and_gradients():
+    """``seqs`` only splits the sum: blocks of one or two sequences give the
+    whole batch's loss and gradients to float32 rounding."""
+    cfg, R, m = _pair("granite-3-2b")
+    params = _f32(R.weights(m, seed=2**31 + 13))
+    tokens = jnp.asarray(R.synthetic_batch(13, 0, cfg.vocab_size, 4, 32))
+    with jax.default_matmul_precision("highest"):
+        whole = jax.jit(lambda p: R.loss_and_grads(m, p, tokens))(params)
+        for seqs in (1, 2):
+            value, grads = jax.jit(lambda p: R.loss_and_grads(m, p, tokens, seqs=seqs))(params)
+            assert abs(float(value) - float(whole[0])) <= 1e-6 * abs(float(whole[0]))
+            for k, g in R.flatten(grads).items():
+                want = np.asarray(R.flatten(whole[1])[k])
+                np.testing.assert_allclose(np.asarray(g), want, rtol=1e-4,
+                                           atol=1e-4 * np.abs(want).max(), err_msg=k)
