@@ -1,11 +1,11 @@
-"""Architecture registry: one module per assigned architecture.
+"""Architecture registry: one module per architecture.
 
-``get(name)`` returns the exact full-size config from the assignment table;
+``get(name)`` returns the full-size config as published;
 ``get_smoke(name)`` returns the reduced same-family config used by the CPU
 smoke tests (tiny widths, few experts, tiny vocab — same code paths).
 
-Shape grid (the assignment's 4 shapes; ``runnable`` encodes the long_500k
-sub-quadratic rule and is recorded as explicit skips in EXPERIMENTS.md).
+Shape grid of the dry run: four shapes; ``cell_runnable`` skips long_500k for
+architectures whose decode state grows with the context.
 """
 from __future__ import annotations
 
@@ -25,6 +25,7 @@ ARCH_IDS = [
     "qwen2_vl_7b",
     "rwkv6_1_6b",
     "jamba_1_5_large_398b",
+    "granite_4_0_h_micro",
 ]
 
 

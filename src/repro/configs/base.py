@@ -1,9 +1,11 @@
 """Model / run configuration system.
 
-One :class:`ModelConfig` expresses every assigned architecture family:
-dense GQA transformers, MoE (incl. dense-residual Arctic style), sliding-
-window attention, encoder-decoder (audio backbone), M-RoPE VLM backbone,
-RWKV6 (attention-free), and Mamba/attention hybrids with interleaved MoE.
+One :class:`ModelConfig` expresses every architecture family in
+``configs/``: dense GQA transformers, MoE (incl. dense-residual Arctic
+style), sliding-window attention, encoder-decoder (audio backbone), M-RoPE
+VLM backbone, RWKV6 (attention-free), Mamba-1/attention hybrids with
+interleaved MoE (Jamba), and Mamba-2/attention hybrids (Granite 4.0-H),
+whose Mamba-2 layers keep a [B, H, P, N] fp32 state and a conv tail.
 
 Layer heterogeneity is expressed as a repeating *block pattern*: a tuple of
 layer descriptors that tiles the depth (e.g. Jamba's 8-layer block with one
@@ -34,14 +36,24 @@ class MoEConfig:
 
 @dataclass(frozen=True)
 class MambaConfig:
+    """State-space mixer sizes. ``n_heads == 0`` is Mamba-1 (``mamba``
+    layers: a [d_inner, d_state] state per sequence). Otherwise Mamba-2
+    (``mamba2`` layers, arXiv:2405.21060): ``n_heads`` heads of ``d_head``
+    channels, each with a scalar decay and a [d_head, d_state] state, B and
+    C shared by the heads of each of ``n_groups`` groups, the scan taken in
+    chunks of ``chunk`` positions. Both convolve with a bias."""
     d_state: int = 16
     d_conv: int = 4
     expand: int = 2
+    n_heads: int = 0
+    d_head: int = 64
+    n_groups: int = 1
+    chunk: int = 256
 
 
 @dataclass(frozen=True)
 class LayerKind:
-    mixer: str  # 'attn' | 'rwkv6' | 'mamba'
+    mixer: str  # 'attn' | 'rwkv6' | 'mamba' | 'mamba2'
     moe: bool = False
 
 
@@ -71,18 +83,23 @@ class ModelConfig:
     vision_len_ratio: int = 0  # 0 = no vision prefix; else S_vis = seq // ratio
     # mixer pattern: 'attn' everywhere by default; 'rwkv6' for ssm family;
     # hybrid uses attn_period (layer i is attention iff i % attn_period ==
-    # attn_offset, else mamba)
+    # attn_offset, else mamba, or mamba2 where mamba.n_heads is set)
     ssm: str | None = None  # None | 'rwkv6' | 'mamba'
     attn_period: int = 0  # 0 = homogeneous
     attn_offset: int = 3
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+    # Granite's scalar multipliers, model constants; the defaults apply none
+    embedding_multiplier: float = 1.0  # on the input embedding
+    attention_multiplier: float | None = None  # the scores' scale; None: head_dim ** -0.5
+    residual_multiplier: float = 1.0  # on each mixer's and feed-forward's output
+    logits_scaling: float = 1.0  # the logits are divided by it
     dtype: str = "bfloat16"
     # runtime / optimizer knobs
     opt_moment_dtype: str = "float32"  # 'bfloat16' for 400B-class
     remat: bool = True
     use_pallas: str = "auto"  # 'auto' | 'on' | 'off'
-    # performance knobs (hillclimbed in EXPERIMENTS.md §Perf)
+    # performance knobs
     seq_shard_residual: bool = True  # Megatron-style sequence parallelism
     attn_q_chunk: int = 1024  # blockwise attention q-chunk (memory roofline)
     attn_unroll_chunks: bool = False  # python-loop chunks (exact HLO flop counts)
@@ -99,6 +116,12 @@ class ModelConfig:
         return self.d_head or (self.d_model // self.n_heads)
 
     @property
+    def attn_scale(self) -> float:
+        if self.attention_multiplier is None:
+            return self.head_dim ** -0.5
+        return self.attention_multiplier
+
+    @property
     def padded_vocab(self) -> int:
         """Vocab padded to a multiple of 256 so it shards evenly over any
         production mesh axis (MaxText-style)."""
@@ -113,9 +136,10 @@ class ModelConfig:
             period = self.attn_period
             moe_every = self.moe.every_k_layers if self.moe else 1
             span = math.lcm(period, moe_every)
+            ssm = "mamba2" if self.mamba.n_heads else "mamba"
             kinds = []
             for i in range(span):
-                mixer = "attn" if i % period == self.attn_offset else "mamba"
+                mixer = "attn" if i % period == self.attn_offset else ssm
                 is_moe = bool(self.moe) and (i % moe_every == moe_every - 1)
                 kinds.append(LayerKind(mixer, is_moe))
             return tuple(kinds)
@@ -135,9 +159,15 @@ class ModelConfig:
 
     @property
     def mamba_d_inner(self) -> int:
+        if self.mamba.n_heads:
+            return self.mamba.n_heads * self.mamba.d_head
         return self.mamba.expand * self.d_model
 
     def replace(self, **kw) -> "ModelConfig":
+        """A copy with ``kw`` changed; ``mamba`` may be a dict of the
+        :class:`MambaConfig` fields that change."""
+        if isinstance(kw.get("mamba"), dict):
+            kw["mamba"] = dataclasses.replace(self.mamba, **kw["mamba"])
         return dataclasses.replace(self, **kw)
 
     # -- parameter counting (for MODEL_FLOPS = 6·N·D roofline term) -------
@@ -155,15 +185,14 @@ class ModelConfig:
         # mamba: in_proj 2*Di*D, conv Di*4, x_proj Di*(dt+2*state), dt_proj, out_proj
         Di, St = self.mamba_d_inner, self.mamba.d_state
         mamba = 2 * Di * D + 4 * Di + Di * (Di // 16 + 2 * St) + Di * Di // 16 + Di * D
+        # mamba2: in_proj to z, x|B|C and dt; conv taps and bias over x|B|C;
+        # dt_bias, A_log, D per head; the gated norm; out_proj
+        Hm, Cm = self.mamba.n_heads, Di + 2 * self.mamba.n_groups * St
+        mamba2 = D * (Di + Cm + Hm) + (self.mamba.d_conv + 1) * Cm + 3 * Hm + Di + Di * D
         total = 0
         active = 0
         for kind in self.pattern:
-            if kind.mixer == "attn":
-                mix = attn
-            elif kind.mixer == "rwkv6":
-                mix = rwkv
-            else:
-                mix = mamba
+            mix = {"attn": attn, "rwkv6": rwkv, "mamba": mamba, "mamba2": mamba2}[kind.mixer]
             if kind.mixer == "rwkv6":
                 ffn_t = ffn_a = 0  # channel-mix is part of the rwkv term
             elif kind.moe:

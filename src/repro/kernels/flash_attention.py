@@ -152,6 +152,7 @@ def flash_attention_bhsd(
     *,
     causal: bool = True,
     window: int | None = None,
+    scale: float | None = None,
     block_q: int = 256,
     block_k: int = 256,
     interpret: bool = False,
@@ -176,7 +177,8 @@ def flash_attention_bhsd(
         lo, hi = kv_span(iq, nk=nk, **shape)
         return (b_, h_ // g, jnp.minimum(jnp.maximum(ik, lo), hi), 0)
 
-    kernel = functools.partial(_flash_fwd_kernel, scale=d**-0.5, nk=nk, **shape)
+    kernel = functools.partial(_flash_fwd_kernel, scale=d**-0.5 if scale is None else scale,
+                               nk=nk, **shape)
     return pl.pallas_call(
         kernel,
         name="flash_fwd",

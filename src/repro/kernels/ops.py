@@ -10,8 +10,8 @@ hand-tiled kernel.
 
 Mosaic kernels cannot be partitioned by the compiler. Given sharding
 ``rules``, each wrapper runs its kernel under ``shard_map``: batch over the
-data-parallel axes and heads (attention, RWKV6) or channels (Mamba) over
-the tensor-parallel axis, each where the size divides. Every shard is then
+data-parallel axes and heads (attention, RWKV6, SSD) or channels (Mamba)
+over the tensor-parallel axis, each where the size divides. Every shard is then
 an independent instance of the same recurrence, so the math is unchanged.
 """
 from __future__ import annotations
@@ -27,6 +27,7 @@ from . import ref
 from .flash_attention import flash_attention_bhsd
 from .mamba import mamba_scan_bsd
 from .rwkv6 import rwkv6_bhsd
+from .ssd import ssd_bshp
 
 
 def _interpret(flag: bool | None) -> bool:
@@ -58,10 +59,13 @@ def _shard(fn, rules, in_specs, out_specs):
 
 
 # ------------------------------------------------------------ flash attention
-def flash_attention(q, k, v, causal=True, window=None, interpret=None, rules=None):
-    """q [B,Sq,H,Dh]; k/v [B,Sk,KV,Dh] -> [B,Sq,H,Dh]."""
+def flash_attention(q, k, v, causal=True, window=None, interpret=None, rules=None,
+                    scale=None):
+    """q [B,Sq,H,Dh]; k/v [B,Sk,KV,Dh] -> [B,Sq,H,Dh]; scores scaled by
+    ``scale`` (Dh ** -0.5 where None)."""
     fn = functools.partial(_flash_attention, causal=causal, window=window,
-                           interpret=interpret)
+                           interpret=interpret,
+                           scale=q.shape[3] ** -0.5 if scale is None else scale)
     if rules is None:
         return fn(q, k, v)
     dp = _axis(rules, rules.batch[0], q.shape[0])
@@ -75,14 +79,14 @@ def flash_attention(q, k, v, causal=True, window=None, interpret=None, rules=Non
     return _shard(fn, rules, (spec, spec, spec), spec)(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention(q, k, v, causal, window, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention(q, k, v, causal, window, interpret, scale):
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     bq, bk = flash_tiles(q.shape[1], k.shape[1], q.shape[3], q.dtype)
     out = flash_attention_bhsd(
-        qt, kt, vt, causal=causal, window=window,
+        qt, kt, vt, causal=causal, window=window, scale=scale,
         block_q=bq, block_k=bk, interpret=_interpret(interpret),
     )
     return jnp.swapaxes(out, 1, 2)
@@ -110,14 +114,14 @@ def flash_tiles(sq: int, sk: int, d: int, dtype) -> tuple[int, int]:
             _tile(sk, sublane, 1024))
 
 
-def _fa_fwd(q, k, v, causal, window, interpret):
-    return _flash_attention(q, k, v, causal, window, interpret), (q, k, v)
+def _fa_fwd(q, k, v, causal, window, interpret, scale):
+    return _flash_attention(q, k, v, causal, window, interpret, scale), (q, k, v)
 
 
-def _fa_bwd(causal, window, interpret, res, g):
+def _fa_bwd(causal, window, interpret, scale, res, g):
     q, k, v = res
-    _, vjp = jax.vjp(lambda q_, k_, v_: ref.attention_ref(q_, k_, v_, causal, window),
-                     q, k, v)
+    _, vjp = jax.vjp(lambda q_, k_, v_: ref.attention_ref(q_, k_, v_, causal, window,
+                                                         scale), q, k, v)
     return vjp(g)
 
 
@@ -193,3 +197,36 @@ def _mamba_bwd(interpret, res, g):
 
 
 _mamba_scan.defvjp(_mamba_fwd, _mamba_bwd)
+
+
+# --------------------------------------------------------------------- ssd
+def ssd(x, dt, A, B_, C_, h0, chunk=256, interpret=None, rules=None):
+    """Mamba-2's SSD scan. x [B,S,H,P]; dt [B,S,H] fp32; A [H]; B_/C_
+    [B,S,G,N]; h0 [B,H,P,N] fp32. Returns (y [B,S,H,P], h [B,H,P,N])."""
+    fn = functools.partial(_ssd, chunk=chunk, interpret=interpret)
+    if rules is None:
+        return fn(x, dt, A, B_, C_, h0)
+    dp = _axis(rules, rules.batch[0], x.shape[0])
+    # heads split over tp only where every shard keeps whole groups
+    tp = _axis(rules, rules.tp, B_.shape[2])
+    seq, st, bc = P(dp, None, tp, None), P(dp, tp, None, None), P(dp, None, tp, None)
+    return _shard(fn, rules, (seq, P(dp, None, tp), P(tp), bc, bc, st),
+                  (seq, st))(x, dt, A, B_, C_, h0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _ssd(x, dt, A, B_, C_, h0, chunk, interpret):
+    return ssd_bshp(x, dt, A, B_, C_, h0, chunk=chunk,
+                    interpret=_interpret(interpret))
+
+
+def _ssd_fwd(x, dt, A, B_, C_, h0, chunk, interpret):
+    return _ssd(x, dt, A, B_, C_, h0, chunk, interpret), (x, dt, A, B_, C_, h0)
+
+
+def _ssd_bwd(chunk, interpret, res, g):
+    _, vjp = jax.vjp(lambda *a: ref.ssd_chunked(*a, chunk=chunk), *res)
+    return vjp(g)
+
+
+_ssd.defvjp(_ssd_fwd, _ssd_bwd)

@@ -33,8 +33,8 @@ def _attn_chunk(
     q_pos: jax.Array | None,  # [qc] global query positions (None = no mask)
     k_pos: jax.Array | None,  # [Sk]
     window: int | None,
+    scale: float,
 ) -> jax.Array:
-    scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k, preferred_element_type=jnp.float32)
     scores = scores * scale
     if q_pos is not None:
@@ -55,19 +55,22 @@ def attention(
     window: int | None = None,
     q_chunk: int = 1024,
     unroll_chunks: bool = False,
+    scale: float | None = None,
 ) -> jax.Array:
-    """Full-sequence attention, query-chunked when Sq > q_chunk.
+    """Full-sequence attention, query-chunked when Sq > q_chunk; scores
+    scaled by ``scale`` (Dh ** -0.5 where None).
 
     ``unroll_chunks`` replaces the chunk loop with a static python loop so
     XLA's cost analysis sees every chunk (used by the dry-run cost modules;
     the runtime default keeps the loop for O(1) HLO size)."""
     b, sq, h, d = q.shape
     kv = k.shape[2]
+    scale = d**-0.5 if scale is None else scale
     qg = _grouped(q, kv)
     q_pos = jnp.arange(sq, dtype=jnp.int32) if causal else None
     k_pos = jnp.arange(k.shape[1], dtype=jnp.int32) if causal else None
     if sq <= q_chunk or sq % q_chunk != 0:
-        out = _attn_chunk(qg, k, v, q_pos, k_pos, window)
+        out = _attn_chunk(qg, k, v, q_pos, k_pos, window, scale)
         return out.reshape(b, sq, h, d)
 
     n_chunks = sq // q_chunk
@@ -82,7 +85,7 @@ def attention(
     @jax.checkpoint
     def one_chunk(args):
         q_c, pos_c = args
-        return _attn_chunk(q_c, k, v, pos_c if causal else None, k_pos, window)
+        return _attn_chunk(q_c, k, v, pos_c if causal else None, k_pos, window, scale)
 
     if unroll_chunks:
         outs = [one_chunk((qs[i], pos[i])) for i in range(n_chunks)]
@@ -100,15 +103,17 @@ def decode_attention(
     pos: jax.Array,  # scalar int32: position of the new token
     *,
     ring: bool = False,
+    scale: float | None = None,
 ) -> jax.Array:
-    """One-token attention against a cache. ``ring=True`` marks a sliding-
-    window ring buffer (every slot is valid once the buffer wrapped; RoPE was
-    applied at insert so slot order is irrelevant to the math)."""
+    """One-token attention against a cache, scores scaled by ``scale`` (Dh
+    ** -0.5 where None). ``ring=True`` marks a sliding-window ring buffer
+    (every slot is valid once the buffer wrapped; RoPE was applied at insert
+    so slot order is irrelevant to the math)."""
     b, _, h, d = q.shape
     kv = k_cache.shape[2]
     s = k_cache.shape[1]
     qg = _grouped(q, kv)  # [B, 1, KV, G, Dh]
-    scale = d**-0.5
+    scale = d**-0.5 if scale is None else scale
     scores = jnp.einsum(
         "bqkgd,bskd->bkgqs", qg, k_cache, preferred_element_type=jnp.float32
     ) * scale

@@ -17,6 +17,14 @@ def rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (normed * scale.astype(jnp.float32)).astype(dtype)
 
 
+def gated_rmsnorm(y: jax.Array, z: jax.Array, scale: jax.Array,
+                  eps: float = 1e-5) -> jax.Array:
+    """Mamba-2's gated norm: RMSNorm of y * silu(z), in fp32, over the last
+    axis; returned in y's dtype."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return rmsnorm(g, scale, eps).astype(y.dtype)
+
+
 def swiglu(x: jax.Array, w1: jax.Array, w3: jax.Array, w2: jax.Array) -> jax.Array:
     """SwiGLU FFN: (silu(x@w1) * (x@w3)) @ w2."""
     gate = jax.nn.silu(x @ w1)

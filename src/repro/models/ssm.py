@@ -1,12 +1,17 @@
-"""Attention-free mixers: RWKV6 (Finch) time-mix and Mamba selective scan.
+"""Attention-free mixers: RWKV6 (Finch) time-mix and Mamba selective scan,
+and the single-token update of Mamba-2's SSD state.
 
-Both provide three execution paths:
+RWKV6 and Mamba-1 provide three execution paths:
   - ``*_naive``  : step-by-step ``lax.scan`` over time — the oracle, used for
                    tests and as the decode single-step math,
   - ``*_chunked``: chunk-parallel formulation used by train/prefill (pure
                    JAX; the Pallas kernels in ``repro.kernels`` mirror this
                    blocking with VMEM tiles),
   - ``*_step``   : single-token decode update.
+
+Mamba-2's prefill and training run the SSD scan of ``kernels/ssd.py`` on
+TPU and its chunked jnp form ``kernels.ref.ssd_chunked`` elsewhere;
+:func:`ssd_step` is its decode update.
 
 Numerics: RWKV6's per-channel log-decay is clamped to ``-MAX_DECAY`` per step
 and chunks are kept short (16) so ``exp(±Σ log w)`` stays inside fp32 range —
@@ -214,3 +219,22 @@ def mamba_step(
     h = a * h + (dt_t * u_t)[..., None] * b_t[:, None, :]
     y = jnp.einsum("bds,bs->bd", h, c_t)
     return y.astype(u_t.dtype), h
+
+
+def ssd_step(
+    x: jax.Array,  # [B, H, P]
+    dt: jax.Array,  # [B, H] fp32, after softplus
+    A: jax.Array,  # [H] fp32
+    B_: jax.Array,  # [B, G, N]
+    C_: jax.Array,  # [B, G, N]
+    h: jax.Array,  # [B, H, P, N] fp32
+) -> tuple[jax.Array, jax.Array]:
+    """One Mamba-2 decode step: h = exp(dt A) h + dt x Bᵀ; y = h C, heads
+    ``g * H/G`` to ``(g + 1) * H/G - 1`` on group ``g``. The state is read and
+    written once, in fp32; y in x's dtype."""
+    r = x.shape[1] // B_.shape[1]
+    bh = jnp.repeat(B_.astype(jnp.float32), r, axis=1)[:, :, None, :]  # [B, H, 1, N]
+    ch = jnp.repeat(C_.astype(jnp.float32), r, axis=1)[:, :, None, :]
+    h = (jnp.exp(dt * A)[..., None, None] * h
+         + (dt[..., None] * x.astype(jnp.float32))[..., None] * bh)
+    return jnp.sum(h * ch, axis=-1).astype(x.dtype), h

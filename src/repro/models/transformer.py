@@ -1,11 +1,13 @@
-"""One configurable stack for all ten assigned architectures.
+"""One configurable stack for every architecture in ``configs/``.
 
-Layer heterogeneity (attention / RWKV6 / Mamba mixers, dense / MoE /
-dense+MoE FFNs, encoder-decoder, M-RoPE, sliding windows) is expressed as a
+Layer heterogeneity (attention / RWKV6 / Mamba-1 / Mamba-2 mixers, dense /
+MoE / dense+MoE FFNs, encoder-decoder, M-RoPE, sliding windows) is expressed as a
 repeating block *pattern* (configs/base.py). Weights for each pattern
 position are stacked along a leading ``n_repeats`` axis and the stack runs
 under ``lax.scan`` — compiled HLO size is O(pattern length), not O(depth),
-which keeps 72-layer Jamba and 56-layer Mixtral dry-runs fast.
+which keeps 72-layer Jamba and 56-layer Mixtral dry-runs fast. Granite's
+multipliers scale the input embedding, the attention scores, each residual
+branch and the logits where the config sets them.
 
 Three entry points per model: ``forward_train`` (full causal sequence),
 ``prefill`` (returns decode state + last-position logits), ``decode_step``
@@ -13,9 +15,11 @@ Three entry points per model: ``forward_train`` (full causal sequence),
   attn  : k/v ring caches  [B, S_cache, KV, Dh]
   rwkv6 : wkv state [B, H, Dh, Dh] (fp32) + token-shift carries [B, D]
   mamba : ssm state [B, Di, St] (fp32) + conv tail [B, K-1, Di]
+  mamba2: ssd state [B, H, P, N] (fp32) + conv tail [B, K-1, Di + 2GN]
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -23,11 +27,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .. import obs
 from ..configs.base import LayerKind, ModelConfig
 from ..distributed.sharding import ShardingRules
+from ..kernels import ref
 from . import ssm
 from .attention import attention, cache_insert, decode_attention
-from .layers import apply_mrope, apply_rope, rmsnorm, swiglu
+from .layers import apply_mrope, apply_rope, gated_rmsnorm, rmsnorm, swiglu
 from .moe import moe_ffn
 from .params import ParamDef
 
@@ -146,6 +152,21 @@ def _mamba_defs(cfg: ModelConfig, r) -> dict:
     }
 
 
+def _mamba2_defs(cfg: ModelConfig, r) -> dict:
+    m, D, Di = cfg.mamba, cfg.d_model, cfg.mamba_d_inner
+    C = Di + 2 * m.n_groups * m.d_state  # conv channels: x | B | C
+    return {
+        "in_proj": ParamDef((D, Di + C + m.n_heads), r.w_in),  # z | x B C | dt
+        "conv_w": ParamDef((C, m.d_conv), P(), "normal", 0.5),
+        "conv_b": ParamDef((C,), P(), "normal", 0.1),
+        "dt_bias": ParamDef((m.n_heads,), P(), "normal", 0.5),
+        "a_log": ParamDef((m.n_heads,), P(), "normal", 0.5),
+        "d_skip": ParamDef((m.n_heads,), P(), "ones"),
+        "norm": ParamDef((Di,), P(), "ones"),
+        "out_proj": ParamDef((Di, D), r.w_out),
+    }
+
+
 def _block_defs(cfg: ModelConfig, r, kind: LayerKind, cross_attn: bool = False) -> dict:
     D = cfg.d_model
     d: dict[str, Any] = {"ln1": ParamDef((D,), P(), "ones")}
@@ -157,6 +178,8 @@ def _block_defs(cfg: ModelConfig, r, kind: LayerKind, cross_attn: bool = False) 
         return d  # rwkv block = time-mix + channel-mix, no swiglu/moe
     elif kind.mixer == "mamba":
         d["mamba"] = _mamba_defs(cfg, r)
+    elif kind.mixer == "mamba2":
+        d["mamba2"] = _mamba2_defs(cfg, r)
     if cross_attn:
         d["ln_x"] = ParamDef((D,), P(), "ones")
         d["xattn"] = _attn_defs(cfg, r)
@@ -181,7 +204,9 @@ def param_defs(cfg: ModelConfig, rules: ShardingRules | None = None) -> dict:
     r = rules if rules is not None else _NullRules()
     D, Vp = cfg.d_model, cfg.padded_vocab
     defs: dict[str, Any] = {
-        "embed": ParamDef((Vp, D), r.embed, "normal", 0.02),
+        # the embedding as the layers see it, times the multiplier, has std
+        # 0.02 (a random model with a larger one predicts its input token)
+        "embed": ParamDef((Vp, D), r.embed, "normal", 0.02 / cfg.embedding_multiplier),
         "final_norm": ParamDef((D,), P(), "ones"),
     }
     if not cfg.tie_embeddings:
@@ -204,7 +229,9 @@ def cache_defs(
     """ParamDef tree matching the decode-state structure that ``prefill``
     produces — used to build ShapeDtypeStructs for the decode dry-run without
     running prefill. Dtypes: KV/conv/shift bf16 (via the dtype argument of
-    :func:`abstract_cache`), SSM states fp32 (marked via ``init='fp32'``)."""
+    :func:`abstract_cache`), SSM states fp32 (marked via ``init='fp32'``).
+    A hybrid model keeps K/V at its attention positions and state and conv
+    tail at its Mamba positions, side by side in one tree."""
     r = rules if rules is not None else _NullRules()
     shardable = batch >= 8
     kv = r.kv_cache(shardable) if rules is not None else P()
@@ -230,6 +257,17 @@ def cache_defs(
             )
             d["shift_t"] = ParamDef((batch, D), P(dp, None) if rules else P())
             d["shift_c"] = ParamDef((batch, D), P(dp, None) if rules else P())
+        elif kind.mixer == "mamba2":
+            m = cfg.mamba
+            d["h"] = ParamDef(
+                (batch, m.n_heads, m.d_head, m.d_state),
+                P(*tuple(st_spec), None, None) if rules is not None else P(),
+                "fp32",
+            )
+            d["conv"] = ParamDef(
+                (batch, m.d_conv - 1, Di + 2 * m.n_groups * m.d_state),
+                P(dp, None, None) if rules is not None else P(),
+            )
         else:  # mamba
             d["h"] = ParamDef(
                 (batch, Di, St),
@@ -246,7 +284,9 @@ def cache_defs(
 
 def abstract_cache(cfg: ModelConfig, rules, batch: int, cache_len: int,
                    enc_len: int = 0, mesh=None, dtype=jnp.bfloat16) -> dict:
-    """ShapeDtypeStruct tree for the decode state (dry-run input)."""
+    """ShapeDtypeStruct tree for the decode state (dry-run input). Adds its
+    bytes to the counters ``cache.kv_bytes`` (keys and values) and
+    ``cache.state_bytes`` (recurrent states, conv tails, token shifts)."""
     from jax.sharding import NamedSharding
 
     defs = cache_defs(cfg, rules, batch, cache_len, enc_len)
@@ -256,6 +296,9 @@ def abstract_cache(cfg: ModelConfig, rules, batch: int, cache_len: int,
         for k, v in node.items():
             if isinstance(v, ParamDef):
                 dt = jnp.float32 if v.init == "fp32" else dtype
+                kind = "kv" if k in ("k", "v", "xk", "xv") else "state"
+                obs.count(f"cache.{kind}_bytes",
+                          math.prod(v.shape) * jnp.dtype(dt).itemsize)
                 if mesh is not None:
                     out[k] = jax.ShapeDtypeStruct(
                         v.shape, dt, sharding=NamedSharding(mesh, v.spec)
@@ -323,17 +366,18 @@ def _self_attention(cfg, rules, p, x, ctx: Ctx, cache):
     ring = cfg.sliding_window is not None
     if ctx.mode == "decode":
         kc, vc = cache_insert(cache["k"], cache["v"], k, v, ctx.pos)
-        out = decode_attention(q, kc, vc, ctx.pos, ring=ring)
+        out = decode_attention(q, kc, vc, ctx.pos, ring=ring, scale=cfg.attn_scale)
         new_cache = {"k": kc, "v": vc}
     else:
         if _use_pallas(cfg) and q.shape[1] % 64 == 0:
             from ..kernels.ops import flash_attention
             out = flash_attention(q, k, v, ctx.causal, cfg.sliding_window,
-                                  rules=rules)
+                                  rules=rules, scale=cfg.attn_scale)
         else:
             out = attention(
                 q, k, v, causal=ctx.causal, window=cfg.sliding_window,
                 q_chunk=cfg.attn_q_chunk, unroll_chunks=cfg.attn_unroll_chunks,
+                scale=cfg.attn_scale,
             )
         if ctx.mode == "prefill":
             new_cache = _prefill_kv_cache(cfg, rules, ctx, k, v)
@@ -478,15 +522,61 @@ def _mamba_mixer(cfg, rules, p, x, ctx: Ctx, cache):
     out = y @ pm["out_proj"]
     new_cache = {}
     if ctx.mode in ("prefill", "decode"):
-        if ctx.mode == "decode":
-            new_conv = jnp.concatenate(
-                [cache["conv"][:, 1:], xr[:, -1:, :].astype(cache["conv"].dtype)], axis=1
-            )
-        else:
-            pad = jnp.zeros((B, max(0, K - 1 - S), Di), xr.dtype)
-            new_conv = jnp.concatenate([pad, xr[:, -(K - 1):, :]], axis=1)
-        new_cache = {"h": hs, "conv": new_conv}
+        new_cache = {"h": hs, "conv": _conv_tail(ctx, cache, xr, K)}
     return out, new_cache
+
+
+def _conv_tail(ctx: Ctx, cache, xr, K: int):
+    """The last K-1 inputs of the causal conv, its state for the next token."""
+    if ctx.mode == "decode":
+        return jnp.concatenate(
+            [cache["conv"][:, 1:], xr[:, -1:, :].astype(cache["conv"].dtype)], axis=1)
+    B, S, C = xr.shape
+    pad = jnp.zeros((B, max(0, K - 1 - S), C), xr.dtype)
+    return jnp.concatenate([pad, xr[:, -(K - 1):, :]], axis=1)
+
+
+def _mamba2_mixer(cfg, rules, p, x, ctx: Ctx, cache):
+    """Mamba-2 (arXiv:2405.21060; Hugging Face ``GraniteMoeHybridMambaLayer``):
+    in_proj to z, x|B|C and dt; causal depthwise conv with bias and silu over
+    x|B|C; dt = softplus(dt + dt_bias), A = -exp(A_log); the SSD scan (the
+    Pallas kernel on TPU, its chunked jnp form elsewhere, one step in decode)
+    plus the D skip; RMSNorm of y * silu(z); out_proj."""
+    pm, m = p["mamba2"], cfg.mamba
+    H, G, N, Ph, K = m.n_heads, m.n_groups, m.d_state, m.d_head, m.d_conv
+    Di = cfg.mamba_d_inner
+    B, S, _ = x.shape
+    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+    z, xbc, dt = jnp.split(h @ pm["in_proj"], [Di, 2 * Di + 2 * G * N], axis=-1)
+    conv = jax.nn.silu(ssm.mamba_conv(xbc, pm["conv_w"], pm["conv_b"],
+                                      cache["conv"] if cache else None))
+    xs, b_, c_ = jnp.split(conv, [Di, Di + G * N], axis=-1)
+    xs = xs.reshape(B, S, H, Ph)
+    b_, c_ = b_.reshape(B, S, G, N), c_.reshape(B, S, G, N)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + pm["dt_bias"].astype(jnp.float32))
+    A = -jnp.exp(pm["a_log"].astype(jnp.float32))
+    if ctx.mode == "decode":
+        y, hs = ssm.ssd_step(xs[:, 0], dt[:, 0], A, b_[:, 0], c_[:, 0], cache["h"])
+        y = y[:, None]
+    else:
+        h0 = jnp.zeros((B, H, Ph, N), jnp.float32)
+        if _use_pallas(cfg):
+            from ..kernels.ops import ssd
+            y, hs = ssd(xs, dt, A, b_, c_, h0, chunk=m.chunk, rules=rules)
+        else:
+            y, hs = ref.ssd_chunked(xs, dt, A, b_, c_, h0, chunk=m.chunk)
+    y = y + pm["d_skip"][:, None].astype(y.dtype) * xs
+    y = gated_rmsnorm(y.reshape(B, S, Di), z, pm["norm"], cfg.norm_eps)
+    out = y @ pm["out_proj"]
+    new_cache = {}
+    if ctx.mode in ("prefill", "decode"):
+        new_cache = {"h": hs, "conv": _conv_tail(ctx, cache, xbc, K)}
+    return out, new_cache
+
+
+def _residual(cfg, branch):
+    m = cfg.residual_multiplier
+    return branch if m == 1 else branch * m
 
 
 def _ffn_or_moe(cfg, rules, kind: LayerKind, p, x, ctx: Ctx):
@@ -512,16 +602,19 @@ def apply_block(cfg, rules, kind: LayerKind, p, x, ctx: Ctx, cache):
     if kind.mixer == "attn":
         with jax.named_scope("attention"):
             mix, new_cache = _self_attention(cfg, rules, p, x, ctx, cache)
+    elif kind.mixer == "mamba2":
+        with jax.named_scope("mamba2"):
+            mix, new_cache = _mamba2_mixer(cfg, rules, p, x, ctx, cache)
     else:
         mix, new_cache = _mamba_mixer(cfg, rules, p, x, ctx, cache)
-    x = x + mix
+    x = x + _residual(cfg, mix)
     if cfg.enc_dec and "xattn" in p:
         xmix, xcache = _cross_attention(cfg, rules, p, x, ctx, cache)
         x = x + xmix
         new_cache = {**new_cache, **xcache}
     with jax.named_scope("ffn"):
         ffn_out, aux = _ffn_or_moe(cfg, rules, kind, p, x, ctx)
-    x = x + ffn_out
+    x = x + _residual(cfg, ffn_out)
     x = _c(x, rules, rules.residual if rules else None)
     return x, new_cache, aux
 
@@ -563,10 +656,15 @@ def _run_blocks(cfg, rules, blocks, x, ctx: Ctx, caches=None, pattern=None):
     return x, new_caches, aux
 
 
+def _embed(cfg, params, tokens) -> jax.Array:
+    x = jnp.take(params["embed"], tokens, axis=0)
+    m = cfg.embedding_multiplier
+    return x if m == 1 else x * m
+
+
 @jax.named_scope("embed")
 def _embed_inputs(cfg, params, batch) -> jax.Array:
-    tokens = batch["tokens"]
-    x = jnp.take(params["embed"], tokens, axis=0)
+    x = _embed(cfg, params, batch["tokens"])
     if cfg.vision_len_ratio and "vision_embeds" in batch:
         ve = batch["vision_embeds"].astype(x.dtype)  # [B, Sv, D]
         x = jnp.concatenate([ve, x[:, ve.shape[1]:, :]], axis=1)
@@ -588,7 +686,8 @@ def _encode(cfg, rules, params, batch, ctx_mode: str):
 def _logits(cfg, params, x) -> jax.Array:
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return x @ head
+    logits = x @ head
+    return logits if cfg.logits_scaling == 1 else logits / cfg.logits_scaling
 
 
 # ================================================================ entry points
@@ -630,7 +729,7 @@ def prefill(cfg: ModelConfig, rules, params, batch, cache_len: int):
 def decode_step(cfg: ModelConfig, rules, params, caches, token, pos):
     """One decode step. token [B,1] int32; pos scalar int32 (position of the
     new token). Returns (logits [B,Vp], new_caches)."""
-    x = jnp.take(params["embed"], token, axis=0)
+    x = _embed(cfg, params, token)
     ctx = Ctx(mode="decode", pos=pos,
               batch_shardable=token.shape[0] >= 8)
     x, new_caches, _ = _run_blocks(cfg, rules, params["blocks"], x, ctx, caches)

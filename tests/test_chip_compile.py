@@ -22,7 +22,8 @@ from repro.distributed.sharding import make_rules
 from repro.kernels import ops
 from repro.launch.mesh import make_host_mesh
 
-# real widths: qwen3 attention, rwkv6_1_6b heads, jamba_1_5_large_398b scan;
+# real widths: qwen3 attention, rwkv6_1_6b heads, jamba_1_5_large_398b scan,
+# granite_4_0_h_micro's SSD scan at its longest served prompt;
 # flash also at granite-3-2b's longest served prefill (S = 31 * 128, tiles
 # 992 wide) and at the qwen3 training step's length
 FLASH = {
@@ -30,9 +31,10 @@ FLASH = {
     "flash_granite_prefill": dict(B=16, H=32, KV=8, S=3968, Dh=64),
     "flash_qwen3_train": dict(B=4, H=16, KV=8, S=4096, Dh=128),
 }
-KERNELS = [*FLASH, "rwkv6", "mamba"]
+KERNELS = [*FLASH, "rwkv6", "mamba", "ssd"]
 RWKV6 = dict(B=2, H=32, S=512, Dh=64)
 MAMBA = dict(B=1, S=512, Di=16384, St=16)
+SSD = dict(B=2, S=2048, H=64, P=64, G=1, N=128)
 
 
 @pytest.fixture(scope="module")
@@ -74,6 +76,17 @@ def _kernel_args(name: str, sds):
             sds((s["H"], s["Dh"]), f32, P("model", None)),
             sds((s["B"], s["H"], s["Dh"], s["Dh"]), f32, P(None, "model")))
         return lambda rules: lambda *a: ops.rwkv6(
+            *a, interpret=False, rules=rules), args
+    if name == "ssd":
+        s = SSD
+        heads = P(None, None, "model", None)
+        args = (sds((s["B"], s["S"], s["H"], s["P"]), bf16, heads),
+                sds((s["B"], s["S"], s["H"]), f32, P(None, None, "model")),
+                sds((s["H"],), f32, P("model")),
+                sds((s["B"], s["S"], s["G"], s["N"]), bf16, P()),
+                sds((s["B"], s["S"], s["G"], s["N"]), bf16, P()),
+                sds((s["B"], s["H"], s["P"], s["N"]), f32, P(None, "model")))
+        return lambda rules: lambda *a: ops.ssd(
             *a, interpret=False, rules=rules), args
     s = MAMBA
     seq = (s["B"], s["S"], s["Di"])

@@ -338,6 +338,101 @@ def test_mamba_model_chunked_vs_naive():
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-5, atol=1e-5)
 
 
+# --------------------------------------------------------------------- ssd
+def _ssd_inputs(rng, b, s, h, p, g, n, dtype, init=True):
+    x = rand(rng, (b, s, h, p), dtype)
+    dt = jax.nn.softplus(jnp.asarray(rng.normal(-1, 1, (b, s, h)), jnp.float32))
+    A = -jnp.exp(jnp.asarray(rng.normal(0, 0.5, (h,)), jnp.float32))
+    B_ = rand(rng, (b, s, g, n), dtype)
+    C_ = rand(rng, (b, s, g, n), dtype)
+    h0 = (jnp.asarray(rng.normal(0, 0.3, (b, h, p, n)), jnp.float32) if init
+          else jnp.zeros((b, h, p, n), jnp.float32))
+    return x, dt, A, B_, C_, h0
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("b,s,h,p,n,chunk,init", [
+    (2, 64, 4, 16, 8, 32, False),  # a multiple of the chunk
+    (1, 100, 4, 16, 24, 32, True),  # padded to the chunk, from a state
+    (1, 256, 16, 16, 32, 128, True),  # blocks of 8 heads, 128 lanes of x
+])
+def test_ssd_kernel_vs_ref(b, s, h, p, n, chunk, init, dtype):
+    rng = np.random.default_rng(11)
+    args = _ssd_inputs(rng, b, s, h, p, 1, n, dtype, init)
+    with jax.default_matmul_precision("highest"):
+        got_y, got_h = ops.ssd(*args, chunk=chunk, interpret=True)
+        want_y, want_h = ref.ssd_chunked(*args, chunk=chunk)
+    assert got_y.shape == (b, s, h, p) and got_y.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got_y, np.float32), np.asarray(want_y, np.float32),
+                               **TOL[dtype])
+    np.testing.assert_allclose(np.asarray(got_h), np.asarray(want_h), rtol=1e-4, atol=1e-4)
+
+
+def _ssd_by_steps(x, dt, A, B_, C_, h0):
+    def step(h, inp):
+        x_t, dt_t, b_t, c_t = inp
+        y, h = model_ssm.ssd_step(x_t, dt_t, A, b_t, c_t, h)
+        return h, y
+
+    seq = (jnp.moveaxis(x, 1, 0), jnp.moveaxis(dt, 1, 0),
+           jnp.moveaxis(B_, 1, 0), jnp.moveaxis(C_, 1, 0))
+    h, ys = jax.lax.scan(step, h0, seq)
+    return jnp.moveaxis(ys, 0, 1), h
+
+
+@pytest.mark.parametrize("s,g,chunk", [(96, 1, 32), (77, 2, 16)])
+def test_ssd_chunked_vs_step_recurrence(s, g, chunk):
+    """The chunked form (prefill, training) and the decode update taken one
+    position at a time give the same outputs and final state."""
+    rng = np.random.default_rng(12)
+    args = _ssd_inputs(rng, 2, s, 4, 8, g, 12, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        y1, h1 = ref.ssd_chunked(*args, chunk=chunk)
+        y2, h2 = _ssd_by_steps(*args)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-4, atol=1e-4)
+
+
+def test_ssd_fast_decay_stays_finite():
+    """A state that decays to nothing within a chunk: every decay is a
+    difference of cumulative sums at or below the diagonal, never positive."""
+    rng = np.random.default_rng(13)
+    x, dt, A, B_, C_, h0 = _ssd_inputs(rng, 1, 128, 4, 16, 1, 8, jnp.float32)
+    A = A * 50.0
+    with jax.default_matmul_precision("highest"):
+        got, _ = ops.ssd(x, dt, A, B_, C_, h0, chunk=64, interpret=True)
+        want, _ = _ssd_by_steps(x, dt, A, B_, C_, h0)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
+def test_ssd_kernel_counts_its_chunks():
+    from repro import obs
+
+    rng = np.random.default_rng(14)
+    args = _ssd_inputs(rng, 2, 100, 4, 16, 1, 8, jnp.float32)
+    before = obs.counters()
+    ops.ssd(*args, chunk=32, interpret=True)
+    after = obs.counters()
+    assert after["ssd.calls"] - before.get("ssd.calls", 0) == 1
+    assert after["ssd.chunks"] - before.get("ssd.chunks", 0) == 2 * 4  # 100 -> 4 chunks
+
+
+def test_ssd_grad_goes_through_the_chunked_form():
+    rng = np.random.default_rng(15)
+    args = _ssd_inputs(rng, 1, 64, 4, 16, 1, 8, jnp.float32)
+
+    def total(f):
+        return lambda *a: jnp.sum(f(*a)[0] ** 2) + jnp.sum(f(*a)[1])
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(total(lambda *a: ops.ssd(*a, chunk=32, interpret=True)),
+                       argnums=(0, 1, 2, 3, 4, 5))(*args)
+        want = jax.grad(total(_ssd_by_steps), argnums=(0, 1, 2, 3, 4, 5))(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-3, atol=1e-3)
+
+
 # --------------------------------------------------- property-based sweeps
 if HAVE_HYP:
 

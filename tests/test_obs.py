@@ -166,11 +166,15 @@ def _kernel(name):
     if name == "rwkv6":
         seq = z(1, 64, 2, 32)
         return (lambda *a: ops.rwkv6(*a, True), (seq, seq, seq, seq, z(2, 32), z(1, 2, 32, 32)))
+    if name == "ssd_fwd":
+        return (lambda *a: ops.ssd(*a, chunk=32, interpret=True),
+                (z(1, 64, 4, 16), z(1, 64, 4), z(4), z(1, 64, 1, 8), z(1, 64, 1, 8),
+                 z(1, 4, 16, 8)))
     return (lambda *a: ops.mamba_scan(*a, True),
             (z(1, 64, 64), z(1, 64, 64), z(64, 8), z(1, 64, 8), z(1, 64, 8), z(1, 64, 8)))
 
 
-@pytest.mark.parametrize("name", ["flash_fwd", "rwkv6", "mamba_scan"])
+@pytest.mark.parametrize("name", ["flash_fwd", "rwkv6", "mamba_scan", "ssd_fwd"])
 def test_each_kernel_carries_its_name(name):
     call, args = _kernel(name)
     text = jax.jit(call).lower(*args).as_text(debug_info=True)

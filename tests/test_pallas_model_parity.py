@@ -12,7 +12,7 @@ from repro.models.params import init_params
 
 
 @pytest.mark.parametrize("arch", ["qwen3_0_6b", "mixtral_8x22b", "rwkv6_1_6b",
-                                  "jamba_1_5_large_398b"])
+                                  "jamba_1_5_large_398b", "granite_4_0_h_micro"])
 def test_pallas_on_vs_off(arch):
     cfg_off = configs.get_smoke(arch).replace(use_pallas="off")
     cfg_on = cfg_off.replace(use_pallas="on")
@@ -28,7 +28,8 @@ def test_pallas_on_vs_off(arch):
     )
 
 
-@pytest.mark.parametrize("arch", ["qwen3_0_6b", "mixtral_8x22b", "jamba_1_5_large_398b"])
+@pytest.mark.parametrize("arch", ["qwen3_0_6b", "mixtral_8x22b", "jamba_1_5_large_398b",
+                                  "granite_4_0_h_micro"])
 def test_pallas_prefill_cache_feeds_decode(arch):
     """The flash-attention prefill (S % 64 == 0) builds the KV cache like the
     jnp path does, so the next decode step gives the same logits."""
