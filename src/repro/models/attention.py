@@ -101,36 +101,49 @@ def decode_attention(
     k_cache: jax.Array,  # [B, S_cache, KV, Dh]
     v_cache: jax.Array,  # [B, S_cache, KV, Dh]
     pos: jax.Array,  # scalar int32: position of the new token
+    k: jax.Array,  # [B, 1, KV, Dh]: the new token's key
+    v: jax.Array,  # [B, 1, KV, Dh]: the new token's value
     *,
-    ring: bool = False,
     scale: float | None = None,
 ) -> jax.Array:
-    """One-token attention against a cache, scores scaled by ``scale`` (Dh
-    ** -0.5 where None). ``ring=True`` marks a sliding-window ring buffer
-    (every slot is valid once the buffer wrapped; RoPE was applied at insert
-    so slot order is irrelevant to the math)."""
+    """One-token attention against the cache as it stood before this token,
+    scores scaled by ``scale`` (Dh ** -0.5 where None).
+
+    The cache's slots below ``pos`` and the new token's own ``k``, ``v``
+    share one softmax; slot ``pos`` mod S_cache, which the new token takes,
+    is left out. In a sliding-window ring that has wrapped, that slot holds
+    the token the window drops (RoPE was applied at insert, so slot order is
+    irrelevant to the math). Nothing here writes the cache:
+    :func:`cache_insert` does."""
     b, _, h, d = q.shape
     kv = k_cache.shape[2]
     s = k_cache.shape[1]
     qg = _grouped(q, kv)  # [B, 1, KV, G, Dh]
     scale = d**-0.5 if scale is None else scale
-    scores = jnp.einsum(
-        "bqkgd,bskd->bkgqs", qg, k_cache, preferred_element_type=jnp.float32
-    ) * scale
+
+    def scores_of(keys):
+        return jnp.einsum(
+            "bqkgd,bskd->bkgqs", qg, keys, preferred_element_type=jnp.float32
+        ) * scale
+
     idx = jnp.arange(s, dtype=jnp.int32)
-    n_valid = jnp.minimum(pos + 1, s) if ring else pos + 1
-    valid = idx[None, :] < n_valid
-    scores = jnp.where(valid[None, None, None, :, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v_cache)
-    return out.reshape(b, 1, h, d)
+    valid = (idx < pos) & (idx != jnp.mod(pos, s))
+    scores = jnp.where(valid[None, None, None, None, :], scores_of(k_cache), NEG_INF)
+    own = scores_of(k)  # [B, KV, G, 1, 1]
+    top = jnp.maximum(scores.max(axis=-1, keepdims=True), own)
+    e, e_own = jnp.exp(scores - top), jnp.exp(own - top)
+    total = e.sum(axis=-1, keepdims=True) + e_own
+    out = jnp.einsum("bkgqs,bskd->bqkgd", (e / total).astype(v_cache.dtype), v_cache,
+                     preferred_element_type=jnp.float32)
+    out = out + jnp.einsum("bkgqs,bskd->bqkgd", (e_own / total).astype(v.dtype), v,
+                           preferred_element_type=jnp.float32)
+    return out.astype(v_cache.dtype).reshape(b, 1, h, d)
 
 
-def cache_insert(
-    k_cache: jax.Array, v_cache: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array
-) -> tuple[jax.Array, jax.Array]:
-    """Write one token's K/V at ``pos`` (mod cache length = ring semantics)."""
-    slot = jnp.mod(pos, k_cache.shape[1])
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
-    return k_cache, v_cache
+def cache_insert(cache: jax.Array, row: jax.Array, layer, pos: jax.Array) -> jax.Array:
+    """Write one layer's new token row ``row`` [B, 1, KV, Dh] into the stacked
+    cache [n, B, S_cache, KV, Dh] at ``layer``, slot ``pos`` mod S_cache (ring
+    semantics). A donated ``cache`` is updated in place."""
+    slot = jnp.mod(pos, cache.shape[2])
+    return jax.lax.dynamic_update_slice(
+        cache, row[None].astype(cache.dtype), (layer, 0, slot, 0, 0))

@@ -363,11 +363,12 @@ def _self_attention(cfg, rules, p, x, ctx: Ctx, cache):
     q, k, v = _project_qkv(cfg, p["attn"], h)
     q, k = _rope(cfg, ctx, q, k)
     new_cache = {}
-    ring = cfg.sliding_window is not None
     if ctx.mode == "decode":
-        kc, vc = cache_insert(cache["k"], cache["v"], k, v, ctx.pos)
-        out = decode_attention(q, kc, vc, ctx.pos, ring=ring, scale=cfg.attn_scale)
-        new_cache = {"k": kc, "v": vc}
+        # the layer's cache is read only; _decode_blocks writes the new rows
+        k, v = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+        out = decode_attention(q, cache["k"], cache["v"], ctx.pos, k, v,
+                               scale=cfg.attn_scale)
+        new_cache = {"k": k, "v": v}
     else:
         if _use_pallas(cfg) and q.shape[1] % 64 == 0:
             from ..kernels.ops import flash_attention
@@ -411,17 +412,15 @@ def _cross_attention(cfg, rules, p, x, ctx: Ctx, cache):
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = (h @ p["xattn"]["wq"]).reshape(B, S, H, Dh)
     new_cache = {}
-    if ctx.mode == "decode":
+    if ctx.mode == "decode":  # the encoder's K/V, read only
         xk, xv = cache["xk"], cache["xv"]
-        new_cache = {"xk": xk, "xv": xv}  # static, re-emitted
-        out = decode_attention(q, xk, xv, jnp.asarray(xk.shape[1] - 1, jnp.int32))
     else:
         mem = ctx.enc_memory
         xk = (mem @ p["xattn"]["wk"]).reshape(B, -1, KV, Dh)
         xv = (mem @ p["xattn"]["wv"]).reshape(B, -1, KV, Dh)
-        out = attention(q, xk, xv, causal=False, q_chunk=cfg.attn_q_chunk)
         if ctx.mode == "prefill":
             new_cache = {"xk": xk, "xv": xv}
+    out = attention(q, xk, xv, causal=False, q_chunk=cfg.attn_q_chunk)
     out = out.reshape(B, S, H * Dh) @ p["xattn"]["wo"]
     return out, new_cache
 
@@ -620,26 +619,25 @@ def apply_block(cfg, rules, kind: LayerKind, p, x, ctx: Ctx, cache):
 
 
 # ================================================================ stacks
-def _run_blocks(cfg, rules, blocks, x, ctx: Ctx, caches=None, pattern=None):
-    """Scan the stacked pattern blocks. Returns (x, new_caches, aux_total)."""
+def _run_blocks(cfg, rules, blocks, x, ctx: Ctx, pattern=None):
+    """Scan the stacked pattern blocks over a whole sequence (train and
+    prefill). Returns (x, new_caches, aux_total); new_caches is the decode
+    state that prefill builds, None in training."""
     pattern = pattern if pattern is not None else cfg.pattern
 
-    def body(carry, xs):
+    def body(carry, layer_params):
         x, aux = carry
-        layer_params, layer_cache = xs
         new_cache = {}
         for i, kind in enumerate(pattern):
             key = f"p{i}"
-            c_in = layer_cache[key] if layer_cache is not None else None
-            x, nc, a = apply_block(cfg, rules, kind, layer_params[key], x, ctx, c_in)
+            x, nc, a = apply_block(cfg, rules, kind, layer_params[key], x, ctx, None)
             aux = aux + a
             new_cache[key] = nc
         return (x, aux), new_cache if new_cache and any(new_cache.values()) else None
 
     fn = jax.checkpoint(body) if (cfg.remat and ctx.mode == "train") else body
     if cfg.scan_layers:
-        (x, aux), new_caches = jax.lax.scan(fn, (x, jnp.zeros((), jnp.float32)),
-                                            (blocks, caches))
+        (x, aux), new_caches = jax.lax.scan(fn, (x, jnp.zeros((), jnp.float32)), blocks)
         return x, new_caches, aux
     # unrolled path (debugging + dry-run cost modules)
     n = jax.tree.leaves(blocks)[0].shape[0]
@@ -647,13 +645,51 @@ def _run_blocks(cfg, rules, blocks, x, ctx: Ctx, caches=None, pattern=None):
     outs = []
     for rep in range(n):
         lp = jax.tree.map(lambda t: t[rep], blocks)
-        lc = jax.tree.map(lambda t: t[rep], caches) if caches is not None else None
-        (x, aux), nc = fn((x, aux), (lp, lc))
+        (x, aux), nc = fn((x, aux), lp)
         outs.append(nc)
     new_caches = (
         jax.tree.map(lambda *ts: jnp.stack(ts), *outs) if outs and outs[0] else None
     )
     return x, new_caches, aux
+
+
+def _decode_blocks(cfg, rules, blocks, x, ctx: Ctx, caches):
+    """Decode's layer loop. The stacked decode state rides in the loop's
+    carry: layer ``i`` reads its slice, and writes back only what it changed,
+    an attention layer its new token's K/V row at slot ``pos`` mod S_cache,
+    a recurrent layer its state, conv tail or token shifts whole; the
+    encoder's K/V pass through. A donated state is so updated in place, with
+    no pass over a whole stack. Tracing adds the bytes one step writes to
+    the counter ``decode.cache_write_bytes``. Returns (x, new_caches)."""
+    n = jax.tree.leaves(blocks)[0].shape[0]
+    written = {}  # pattern key -> bytes one layer writes
+
+    def body(carry, xs):
+        x, state = carry
+        layer_params, i = xs
+        state = dict(state)
+        for j, kind in enumerate(cfg.pattern):
+            key = f"p{j}"
+            stacks = state[key]
+            layer = {name: jax.lax.dynamic_index_in_dim(t, i, keepdims=False)
+                     for name, t in stacks.items()}
+            x, new, _ = apply_block(cfg, rules, kind, layer_params[key], x, ctx, layer)
+            written[key] = sum(v.size * stacks[name].dtype.itemsize for name, v in new.items())
+            state[key] = {
+                name: t if name not in new
+                else cache_insert(t, new[name], i, ctx.pos) if name in ("k", "v")
+                else jax.lax.dynamic_update_index_in_dim(t, new[name].astype(t.dtype), i, 0)
+                for name, t in stacks.items()
+            }
+        return (x, state), None
+
+    if cfg.scan_layers:
+        (x, caches), _ = jax.lax.scan(body, (x, caches), (blocks, jnp.arange(n)))
+    else:  # unrolled path (debugging + dry-run cost modules)
+        for rep in range(n):
+            (x, caches), _ = body((x, caches), (jax.tree.map(lambda t: t[rep], blocks), rep))
+    obs.count("decode.cache_write_bytes", n * sum(written.values()))
+    return x, caches
 
 
 def _embed(cfg, params, tokens) -> jax.Array:
@@ -676,8 +712,7 @@ def _encode(cfg, rules, params, batch, ctx_mode: str):
     enc_x = batch["encoder_embeds"].astype(params["enc_final_norm"].dtype)
     ectx = Ctx(mode="train", causal=False)
     enc_x, _, _ = _run_blocks(
-        cfg, rules, params["enc_blocks"], enc_x, ectx,
-        caches=None, pattern=(LayerKind("attn"),),
+        cfg, rules, params["enc_blocks"], enc_x, ectx, pattern=(LayerKind("attn"),),
     )
     return rmsnorm(enc_x, params["enc_final_norm"], cfg.norm_eps)
 
@@ -732,5 +767,5 @@ def decode_step(cfg: ModelConfig, rules, params, caches, token, pos):
     x = _embed(cfg, params, token)
     ctx = Ctx(mode="decode", pos=pos,
               batch_shardable=token.shape[0] >= 8)
-    x, new_caches, _ = _run_blocks(cfg, rules, params["blocks"], x, ctx, caches)
+    x, new_caches = _decode_blocks(cfg, rules, params["blocks"], x, ctx, caches)
     return _logits(cfg, params, x)[:, 0], new_caches

@@ -119,6 +119,31 @@ def test_decode_matches_forward(arch):
         )
 
 
+@pytest.mark.parametrize("prompt", [3, 8])
+def test_decode_across_the_sliding_window_ring(prompt):
+    """Decode from a prompt that does not fill the sliding window's ring
+    (length 8) to past its second wrap: before the wrap the slots below pos
+    count, after it every slot but pos mod 8, which holds the token that
+    leaves the window. Each step gives the forward logits (fp32, as above)."""
+    cfg = configs.get_smoke("mixtral_8x22b")
+    assert cfg.sliding_window == 8
+    params = init_params(T.param_defs(cfg), seed=0, dtype=jnp.float32)
+    S = 20
+    batch = make_batch(cfg, 2, S)
+    full_logits, _ = jax.jit(lambda p, b: T.forward_train(cfg, None, p, b))(params, batch)
+    caches, _ = jax.jit(lambda p, b: T.prefill(cfg, None, p, b, cache_len=S))(
+        params, {"tokens": batch["tokens"][:, :prompt]})
+    assert caches["p0"]["k"].shape[2] == 8
+    step = jax.jit(lambda p, c, t, pos: T.decode_step(cfg, None, p, c, t, pos))
+    for pos in range(prompt, S):
+        logits, caches = step(params, caches, batch["tokens"][:, pos:pos + 1],
+                              jnp.asarray(pos, jnp.int32))
+        np.testing.assert_allclose(
+            np.asarray(logits, np.float32), np.asarray(full_logits[:, pos], np.float32),
+            rtol=3e-2, atol=3e-2, err_msg=f"decode at position {pos} mismatch",
+        )
+
+
 @pytest.mark.parametrize("arch", ["internlm2_20b", "rwkv6_1_6b", "jamba_1_5_large_398b"])
 def test_scan_vs_unrolled_layers(arch):
     """lax.scan over stacked layers == python-loop over layers."""

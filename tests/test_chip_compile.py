@@ -1,4 +1,5 @@
-"""The Pallas kernels compile for a TPU v5e at real widths.
+"""The Pallas kernels compile for a TPU v5e at real widths, and the served
+models' decode steps update their donated caches in place.
 
 Compiled against a described ``v5e:2x2`` topology: the TPU compiler runs
 here, with no chip attached, and refuses what the chip would refuse (tile
@@ -10,6 +11,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 import os
+import re
 
 import pytest
 
@@ -18,9 +20,13 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import configs
 from repro.distributed.sharding import make_rules
 from repro.kernels import ops
 from repro.launch.mesh import make_host_mesh
+from repro.models import transformer as T
+from repro.models.params import abstract_params
+from repro.train.steps import make_decode_step
 
 # real widths: qwen3 attention, rwkv6_1_6b heads, jamba_1_5_large_398b scan,
 # granite_4_0_h_micro's SSD scan at its longest served prompt;
@@ -121,3 +127,44 @@ def test_kernel_compiles_under_shard_map_on_2x2(topo, name):
         name, lambda shape, dtype, spec: jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(mesh, spec)))
     _assert_kernel_in(jax.jit(make(make_rules(mesh))).lower(*args).compile())
+
+
+# the served models' decode steps at their cells' batch and longest cache
+DECODE = {
+    "granite_3_2b": dict(B=16, L=4096),
+    "granite_4_0_h_micro": dict(B=32, L=2560),
+}
+
+
+@pytest.mark.parametrize("arch", DECODE)
+def test_decode_step_updates_its_donated_cache_in_place(topo, arch):
+    """With the caches donated, the compiled step asks temporaries under a
+    tenth of the caches' bytes and copies no whole K or V stack: each layer's
+    new row goes into the stack where it lies."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg, s = configs.get(arch), DECODE[arch]
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one), tree)
+
+    params = on_chip(abstract_params(T.param_defs(cfg), jnp.bfloat16))
+    caches = on_chip(T.abstract_cache(cfg, None, s["B"], s["L"]))
+    token = jax.ShapeDtypeStruct((s["B"], 1), jnp.int32, sharding=one)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+    compiled = jax.jit(make_decode_step(cfg, None), donate_argnums=(1,)).lower(
+        params, caches, token, pos).compile()
+
+    cache_bytes = sum(t.size * t.dtype.itemsize for t in jax.tree.leaves(caches))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < cache_bytes / 10, (temp, cache_bytes)
+    stacks = {tuple(layer[name].shape) for layer in caches.values()
+              for name in ("k", "v") if name in layer}
+    assert stacks
+    copied = set()
+    for line in compiled.as_text().splitlines():
+        op = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) copy(?:-start)?\(", line)
+        if op:
+            copied |= {tuple(int(d) for d in dims.split(",") if d)
+                       for dims in re.findall(r"\[([\d,]*)\]", op.group(1))}
+    assert not stacks & copied, stacks & copied

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 
 from repro import configs, obs
 from repro.models import transformer as T
-from repro.models.params import init_params, param_count
+from repro.models.params import abstract_params, init_params, param_count
 from repro.train.steps import make_decode_step, make_prefill_step
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -44,6 +44,29 @@ def test_hybrid_cache_holds_both_kinds_of_state():
     grew = {k: after[k] - before.get(k, 0) for k in ("cache.kv_bytes", "cache.state_bytes")}
     assert grew["cache.kv_bytes"] == 4 * 2 * 32 * 2560 * 8 * 64 * 2
     assert grew["cache.state_bytes"] == 36 * 32 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
+
+
+@pytest.mark.parametrize("arch", ["granite_3_2b", "granite_4_0_h_micro"])
+def test_decode_step_counts_the_bytes_it_writes(arch):
+    """Tracing one decode step adds to ``decode.cache_write_bytes`` what the
+    step writes into its decode state: each attention layer's new K/V row in
+    bf16, and each Mamba-2 layer's fp32 state and bf16 conv tail whole."""
+    cfg = configs.get_smoke(arch)
+    B = 3
+    params = abstract_params(T.param_defs(cfg), jnp.bfloat16)
+    caches = T.abstract_cache(cfg, None, B, 40)
+    token = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    before = obs.counters().get("decode.cache_write_bytes", 0)
+    jax.eval_shape(make_decode_step(cfg, None), params, caches, token,
+                   jax.ShapeDtypeStruct((), jnp.int32))
+    grew = obs.counters()["decode.cache_write_bytes"] - before
+    kinds = [k.mixer for k in cfg.pattern] * cfg.n_repeats
+    rows = kinds.count("attn") * 2 * B * cfg.n_kv_heads * cfg.head_dim * 2
+    m = cfg.mamba
+    states = kinds.count("mamba2") * B * (
+        m.n_heads * m.d_head * m.d_state * 4
+        + (m.d_conv - 1) * (cfg.mamba_d_inner + 2 * m.n_groups * m.d_state) * 2)
+    assert rows and grew == rows + states
 
 
 def _prefill_decode(cfg, params, tokens, prompt, break_at=None, leaf=None):
